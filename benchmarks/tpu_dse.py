@@ -14,6 +14,7 @@ from __future__ import annotations
 from repro.configs.base import ARCH_IDS, get_config
 from repro.core import dse
 from repro.core.hardware import TPU_V5E
+from repro.core.memory_model import fits_vmem_bytes
 from repro.core.tiling import GemmProblem
 
 # per-device M for train_4k on the 16x16 mesh: (256/16) rows x 4096 seq
@@ -65,7 +66,7 @@ def run(report) -> None:
         ridge = (chip.peak_int8_ops if p.in_dtype == "int8"
                  else chip.peak_bf16_flops) / chip.hbm_bw
         ok = (t.mxu_aligned(chip)
-              and best.vmem_bytes <= 0.75 * chip.vmem_bytes)
+              and fits_vmem_bytes(best.vmem_bytes, chip))
         if name.startswith("square") and p.m >= 2048:
             # large square GEMMs must tile compute-bound (paper regime)
             ok = ok and best.traffic.bound == "compute"

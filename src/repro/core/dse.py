@@ -88,7 +88,7 @@ def _lane_candidates(dim: int) -> Sequence[int]:
 def _solve_cached(m: int, k: int, n: int, a_dtype: str, b_dtype: str,
                   out_dtype: str, acc_dtype: str, epilogue: str,
                   n_b_operands: int, n_groups: int, chip_name: str,
-                  budget_fraction: float, top: int, cal_version: int
+                  top: int, cal_version: int
                   ) -> Tuple["TileDesign", ...]:
     assert chip_name == TPU_V5E.name, "single-target build"
     chip = TPU_V5E
@@ -109,7 +109,7 @@ def _solve_cached(m: int, k: int, n: int, a_dtype: str, b_dtype: str,
                     tile = TileConfig(bm, bk, bn, strategy)
                     if not tile.mxu_aligned(chip):
                         continue
-                    if not fits_vmem(tile, p, chip, budget_fraction):
+                    if not fits_vmem(tile, p, chip):
                         continue
                     designs.append(TileDesign(
                         tile=tile,
@@ -124,8 +124,7 @@ def _solve_cached(m: int, k: int, n: int, a_dtype: str, b_dtype: str,
     return tuple(designs[:top])
 
 
-def solve(p: GemmProblem, chip: TPUChip = TPU_V5E,
-          budget_fraction: float = 0.75, top: int = 10
+def solve(p: GemmProblem, chip: TPUChip = TPU_V5E, top: int = 10
           ) -> List[TileDesign]:
     """Ranked tiling designs for a GEMM problem.  The memo key includes
     the cost-model calibration version: applying measured constants
@@ -133,8 +132,7 @@ def solve(p: GemmProblem, chip: TPUChip = TPU_V5E,
     pre-calibration answers."""
     return list(_solve_cached(p.m, p.k, p.n, p.a_dtype, p.b_dtype,
                               p.out_dtype, p.acc_dtype, p.epilogue,
-                              p.n_b_operands, p.n_groups, chip.name,
-                              budget_fraction, top,
+                              p.n_b_operands, p.n_groups, chip.name, top,
                               calibration_version()))
 
 

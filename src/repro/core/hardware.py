@@ -3,8 +3,10 @@
 Three devices appear in this framework:
 
 * ``TPU_V5E`` — the *target* device for the adapted framework (kernels,
-  sharding, roofline). Constants match the task sheet: 197 TFLOP/s bf16,
-  819 GB/s HBM, ~50 GB/s per ICI link.
+  sharding, roofline). Peaks are the published ones (Google Cloud
+  documentation, "TPU v5e"): 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB of
+  HBM at 819 GB/s.  :func:`chip_for_kind` maps a device's
+  ``device_kind`` to its constants and refuses a kind it does not know.
 * ``VERSAL_VC1902`` and ``STRATIX_NX2100`` — the paper's devices (Table I),
   used by :mod:`repro.core.paper_model` to reproduce the paper's analytical
   results (Tables II–IV) faithfully.
@@ -25,10 +27,11 @@ class TPUChip:
 
     name: str
     peak_bf16_flops: float          # FLOP/s
-    peak_int8_ops: float            # OP/s (2x bf16 on v5e MXU)
+    peak_int8_ops: float            # OP/s
     hbm_bytes: int                  # HBM capacity per chip
     hbm_bw: float                   # bytes/s
     vmem_bytes: int                 # VMEM scratchpad per core
+    vmem_limit_bytes: int           # scoped VMEM one kernel may ask for
     ici_link_bw: float              # bytes/s per link, per direction
     ici_links: int                  # torus links per chip
     dcn_bw: float                   # bytes/s per chip for pod-to-pod traffic
@@ -48,14 +51,34 @@ class TPUChip:
 TPU_V5E = TPUChip(
     name="tpu_v5e",
     peak_bf16_flops=197e12,
-    peak_int8_ops=394e12,
+    peak_int8_ops=393e12,
     hbm_bytes=16 * GiB,
     hbm_bw=819e9,
     vmem_bytes=128 * MiB,
+    # v5e compiles take a requested scoped limit up to the 128 MiB of
+    # VMEM, but refuse a kernel whose allocations pass 128 MiB in all (an
+    # aie tile modeled at 120 MiB allocated 129.85 MiB); 100 MiB leaves
+    # the rest to allocations the model does not bill.
+    vmem_limit_bytes=100 * MiB,
     ici_link_bw=50e9,
     ici_links=4,            # 2D torus on v5e: 4 links
     dcn_bw=25e9,            # conservative per-chip share of pod-to-pod DCN
 )
+
+
+#: chip constants keyed by ``jax.Device.device_kind``
+CHIPS = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_for_kind(device_kind: str) -> TPUChip:
+    """The constants of a TPU by its ``device_kind``; an unknown kind is
+    an error, never a default."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no chip constants for device kind {device_kind!r}; known: "
+            f"{sorted(CHIPS)}") from None
 
 
 @dataclasses.dataclass(frozen=True)

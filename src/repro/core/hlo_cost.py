@@ -517,13 +517,3 @@ def _group_size(line: str) -> int:
     if m:
         return len(m.group(1).split(","))
     return 1
-
-
-def xla_cost(compiled) -> dict:
-    """``compiled.cost_analysis()`` normalized across jax versions
-    (older jax returns a per-computation list of dicts, newer a single
-    dict); always a dict, empty when the backend reports nothing."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)

@@ -12,9 +12,10 @@ and the DSE (:mod:`repro.core.dse`) uses it as its capacity constraint.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict
 
-from repro.core.hardware import TPU_V5E, TPUChip
+from repro.core.hardware import MiB, TPU_V5E, TPUChip
 from repro.core.tiling import (
     GemmProblem,
     TileConfig,
@@ -25,6 +26,14 @@ from repro.core.tiling import (
 
 # Pallas pipelines HBM->VMEM streams with two in-flight stages.
 PIPELINE_STAGES = 2
+
+#: a kernel's scoped VMEM limit over its modeled working set: room for
+#: what the model does not bill.  In v5e compiles the least limit a
+#: kernel needed was at most 1.11x its modeled set
+VMEM_HEADROOM = 1.25
+
+#: the compiler's default scoped VMEM limit; no kernel asks for less
+DEFAULT_SCOPED_VMEM = 16 * MiB
 
 
 def padded_tile_bytes(rows: int, cols: int, dtype, chip: TPUChip = TPU_V5E
@@ -47,12 +56,13 @@ class VmemFootprint:
     scale_bytes: int = 0          # fused-dequant fp32 scale vector blocks
     bias_bytes: int = 0           # fused-epilogue (1, bn) f32 bias blocks
     residual_bytes: int = 0       # fused-epilogue (bm, bn) residual stream
+    temp_bytes: int = 0           # (bm, bn) f32 kernel-body temporaries
 
     @property
     def total(self) -> int:
         return (self.a_bytes + self.b_bytes + self.out_bytes
                 + self.acc_bytes + self.scale_bytes + self.bias_bytes
-                + self.residual_bytes)
+                + self.residual_bytes + self.temp_bytes)
 
     def as_dict(self) -> Dict[str, int]:
         return dataclasses.asdict(self) | {"total": self.total}
@@ -77,6 +87,12 @@ def vmem_footprint(tile: TileConfig, p: GemmProblem,
     doubles the B stream, the scale blocks and the accumulator scratch;
     a fused epilogue (``p.epilogue``) adds its (1, bn) f32 bias blocks
     and/or its (bm, bn) out-dtype residual stream.
+
+    The kernel body adds (bm, bn) f32 temporaries the compiler
+    materializes: one for an ``aie`` flush that applies an activation
+    (the gated kernel always does), whose input it reads twice
+    (``x * sigmoid(x)``), and one per B operand widened from int8 in the
+    kernel (W8A16), whose dot result does not accumulate in place.
 
     Grouped ragged GEMMs (``p.n_groups > 0``) have the ``aie`` working
     set exactly: each instance streams one (bm, bk) A block and one
@@ -104,6 +120,11 @@ def vmem_footprint(tile: TileConfig, p: GemmProblem,
     if ep.residual:
         residual = PIPELINE_STAGES * padded_tile_bytes(
             tile.bm, tile.bn, p.out_dtype, chip)
+    widened = p.b_dtype == "int8" and p.a_dtype != "int8"
+    temps = p.n_b_operands if widened else 0
+    if tile.strategy == "aie" and ep.activation:
+        temps += 1
+    temp = temps * padded_tile_bytes(tile.bm, tile.bn, "float32", chip)
     if tile.strategy == "aie":
         return VmemFootprint(
             a_bytes=PIPELINE_STAGES * a,
@@ -113,6 +134,7 @@ def vmem_footprint(tile: TileConfig, p: GemmProblem,
             scale_bytes=scale,
             bias_bytes=bias,
             residual_bytes=residual,
+            temp_bytes=temp,
         )
     # 'tb': A resident; C is both input and output stream (read-modify-
     # write accumulation in the output buffer, like the paper's PL adders).
@@ -125,6 +147,7 @@ def vmem_footprint(tile: TileConfig, p: GemmProblem,
         scale_bytes=scale,
         bias_bytes=bias,
         residual_bytes=residual,
+        temp_bytes=temp,
     )
 
 
@@ -141,9 +164,21 @@ def vmem_efficiency(tile: TileConfig, p: GemmProblem,
     return logical / (a + b + o)
 
 
-def fits_vmem(tile: TileConfig, p: GemmProblem, chip: TPUChip = TPU_V5E,
-              budget_fraction: float = 0.75) -> bool:
-    """Capacity constraint (eq. 7-8/15 analogue).  ``budget_fraction``
-    reserves headroom for the compiler's own VMEM needs."""
-    return vmem_footprint(tile, p, chip).total \
-        <= budget_fraction * chip.vmem_bytes
+def fits_vmem_bytes(footprint: int, chip: TPUChip = TPU_V5E) -> bool:
+    """Whether a kernel with this modeled working set, plus headroom,
+    stays inside the scoped VMEM limit the compiler accepts."""
+    return footprint * VMEM_HEADROOM <= chip.vmem_limit_bytes
+
+
+def fits_vmem(tile: TileConfig, p: GemmProblem, chip: TPUChip = TPU_V5E
+              ) -> bool:
+    """Capacity constraint (eq. 7-8/15 analogue) of one GEMM tile."""
+    return fits_vmem_bytes(vmem_footprint(tile, p, chip).total, chip)
+
+
+def vmem_limit_bytes(footprint: int, chip: TPUChip = TPU_V5E) -> int:
+    """The scoped VMEM limit a kernel with this modeled working set asks
+    the compiler for: the footprint plus headroom, never below the
+    compiler's default and never above the chip's limit."""
+    want = math.ceil(footprint * VMEM_HEADROOM)
+    return min(chip.vmem_limit_bytes, max(DEFAULT_SCOPED_VMEM, want))

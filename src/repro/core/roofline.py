@@ -145,7 +145,7 @@ def analyze(compiled, *, chip: TPUChip = TPU_V5E, int8: bool = False,
     keeps the datasheet ICI rate (no calibration source measures it).
     """
     from repro.core.bandwidth import effective_rates
-    cost = hlo_cost.xla_cost(compiled)
+    cost = compiled.cost_analysis()
     text = hlo_text if hlo_text is not None else compiled.as_text()
     parsed = hlo_cost.analyze_text(text)
     peak, hbm_bw = effective_rates(chip, int8)

@@ -24,20 +24,18 @@ Every resolution is divisibility-checked against the actual dim size:
 a dim that does not divide its mesh axes falls back to replicated
 instead of failing, mirroring the layout engine's relaxation rule.
 
-The module also hosts the version-compat wrappers :func:`make_mesh` and
-:func:`shard_map` — the repo targets the jax_pallas toolchain baked into
-the image, whose mesh/shard_map signatures drifted across releases
-(``axis_types=`` and ``check_vma=`` exist only on newer jax).
+:func:`make_mesh` builds every mesh of the repo with ``Auto`` axes, the
+GSPMD partitioning the layout engine's shardings are written for.
 """
 
 from __future__ import annotations
 
 import contextlib
 import os
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # ---------------------------------------------------------------------------
 # Mesh lifecycle
@@ -170,41 +168,44 @@ def act(x: jax.Array, *axes) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-# ---------------------------------------------------------------------------
-# jax version compat
-# ---------------------------------------------------------------------------
+def per_device(fn: Callable, args: Sequence, axes: Sequence, out_axes):
+    """``fn(*args)`` run as one program per device of the active mesh.
+
+    GSPMD cannot partition a Mosaic (Pallas TPU) kernel, so under a
+    multi-device mesh the call goes through ``jax.shard_map``.  Each
+    ``axes`` entry gives an argument's logical axes as for :func:`act`
+    (``None`` arguments pass through untouched); ``out_axes`` gives the
+    result's.  A dim named by no logical axis, or one its mesh axes do
+    not divide, is whole on every device, so the logical axes may only
+    split dims that ``fn`` treats independently.  Outside a mesh, on one
+    device, or already inside a ``shard_map``, ``fn`` runs as is.
+    """
+    mesh = current_mesh()
+    if (not isinstance(mesh, Mesh) or mesh_devices(mesh) <= 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return fn(*args)
+    sizes = axis_sizes(mesh)
+    live = [i for i, x in enumerate(args) if x is not None]
+
+    def local(*shards):
+        full = list(args)
+        for i, x in zip(live, shards):
+            full[i] = x
+        return fn(*full)
+
+    out = jax.eval_shape(fn, *args)
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=tuple(logical_spec(args[i].shape, axes[i], sizes)
+                       for i in live),
+        out_specs=logical_spec(out.shape, out_axes, sizes),
+        check_vma=False)(*(args[i] for i in live))
+
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, devices=None) -> Mesh:
-    """``jax.make_mesh`` across jax versions.
-
-    Newer jax wants explicit ``axis_types=(AxisType.Auto, ...)`` for
-    meshes used with GSPMD auto partitioning; older jax predates the
-    kwarg (and Auto is the only behavior).  Try rich -> plain.
-    """
-    kwargs = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                tuple(axis_shapes), tuple(axis_names),
-                axis_types=(axis_type.Auto,) * len(tuple(axis_names)),
-                **kwargs)
-        except TypeError:
-            pass
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map`` across jax versions (``check_vma`` vs ``check_rep``)."""
-    top = getattr(jax, "shard_map", None)
-    if top is not None:
-        for kw in ({"check_vma": check}, {"check_rep": check}, {}):
-            try:
-                return top(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)

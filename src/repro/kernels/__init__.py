@@ -1,21 +1,16 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-
-"""Pallas-TPU version compat.
-
-The TPU compiler-params dataclass was renamed across jax releases
-(``pltpu.TPUCompilerParams`` -> ``pltpu.CompilerParams``); resolve
-whichever the pinned toolchain ships so every kernel builds on both.
-"""
+"""Pallas TPU kernels and the planned GEMM / attention dispatch."""
 
 
-def _compiler_params(**kwargs):
-    """``pltpu.CompilerParams(**kwargs)`` under either name."""
+def _compiler_params(dimension_semantics, vmem_bytes: int):
+    """Mosaic compiler params of every ``pallas_call``: the grid's
+    dimension semantics and a scoped VMEM limit derived from the plan's
+    modeled working set ``vmem_bytes``
+    (:func:`repro.core.memory_model.vmem_limit_bytes`)."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) \
-        or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)
+    from repro.core.memory_model import vmem_limit_bytes
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics),
+        vmem_limit_bytes=vmem_limit_bytes(vmem_bytes))
 
 
 def acc_dtype(in_dtype):

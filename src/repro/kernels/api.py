@@ -55,9 +55,10 @@ from repro.core import dse
 from repro.core.bandwidth import TrafficEstimate, estimate
 from repro.core.hardware import TPU_V5E
 from repro.core.memory_model import VmemFootprint, fits_vmem, \
-    vmem_efficiency, vmem_footprint
+    vmem_efficiency, vmem_footprint, vmem_limit_bytes
 from repro.core.tiling import STRATEGIES, GemmProblem, TileConfig, \
     grouped_instances, round_up
+from repro.dist import sharding as shd
 from repro.kernels import ref as _ref
 from repro.kernels.epilogue import ACTIVATIONS, Epilogue
 from repro.kernels.gemm_aie import gemm_aie
@@ -350,7 +351,6 @@ class GemmPlan:
         if s.gated:
             b_desc = "2x " + b_desc
         gm, gn, gk = t.grid(p)
-        budget = 0.75 * TPU_V5E.vmem_bytes
         lines = [
             f"GemmPlan {self.m}x{self.k}x{self.n}  A {p.a_dtype}  "
             f"B {b_desc}  -> {p.out_dtype} (acc {p.acc_dtype})",
@@ -359,8 +359,9 @@ class GemmPlan:
             f"{'  (user override)' if s.tile is not None else ''}  "
             f"grid (gm,gn,gk)=({gm},{gn},{gk})  "
             f"pad eff {t.tile_efficiency(p):.0%}",
-            f"  vmem     : {self.vmem.total / 2**20:.2f} MiB of "
-            f"{budget / 2**20:.0f} MiB budget  "
+            f"  vmem     : {self.vmem.total / 2**20:.2f} MiB, scoped "
+            f"limit {vmem_limit_bytes(self.vmem.total) / 2**20:.0f} of "
+            f"{TPU_V5E.vmem_limit_bytes / 2**20:.0f} MiB  "
             f"(a {self.vmem.a_bytes >> 10} KiB, b {self.vmem.b_bytes >> 10}"
             f" KiB, acc {self.vmem.acc_bytes >> 10} KiB)  "
             f"eff {vmem_efficiency(t, p):.0%}",
@@ -469,8 +470,8 @@ def _infeasible_reason(tile: TileConfig, p: GemmProblem) -> Optional[str]:
     if fits_vmem(tile, p):
         return None
     return (f"VMEM footprint {vmem_footprint(tile, p).total / 2**20:.1f} "
-            f"MiB exceeds the {0.75 * TPU_V5E.vmem_bytes / 2**20:.0f} "
-            "MiB budget")
+            f"MiB with headroom exceeds the "
+            f"{TPU_V5E.vmem_limit_bytes / 2**20:.0f} MiB scoped limit")
 
 
 def plan(spec: GemmSpec, shapes: Tuple[int, ...]) -> GemmPlan:
@@ -645,13 +646,15 @@ def _pad2(x, m_to, n_to):
 
 
 def _gemm_pallas(a: jax.Array, b: jax.Array, tile: TileConfig,
-                 out_dtype, *, b_scale: Optional[jax.Array] = None,
+                 out_dtype, vmem_bytes: int, *,
+                 b_scale: Optional[jax.Array] = None,
                  bias: Optional[jax.Array] = None,
                  residual: Optional[jax.Array] = None,
                  out_scale: Optional[jax.Array] = None,
                  activation: Optional[str] = None) -> jax.Array:
     m, k = a.shape
     _, n = b.shape
+    tile = _clamp_tile(tile, m, k, n)
     bm, bk, bn = tile.bm, tile.bk, tile.bn
     mp, kp, np_ = round_up(m, bm), round_up(k, bk), round_up(n, bn)
     ap = _pad2(a, mp, kp)
@@ -666,14 +669,16 @@ def _gemm_pallas(a: jax.Array, b: jax.Array, tile: TileConfig,
     fn = gemm_aie if tile.strategy == "aie" else gemm_tb
     out = fn(ap, bp, tile=tile, out_dtype=out_dtype, b_scale=sp,
              bias=biasp, residual=resp, out_scale=out_scale,
-             activation=activation, interpret=_interpret())
+             activation=activation, interpret=_interpret(),
+             vmem_bytes=vmem_bytes)
     return out[:m, :n]
 
 
-def _gated_pallas(a, bg, bu, tile, out_dtype, activation,
+def _gated_pallas(a, bg, bu, tile, out_dtype, activation, vmem_bytes,
                   sg=None, su=None) -> jax.Array:
     m, k = a.shape
     _, n = bg.shape
+    tile = _clamp_tile(tile, m, k, n)
     bm, bk, bn = tile.bm, tile.bk, tile.bn
     mp, kp, np_ = round_up(m, bm), round_up(k, bk), round_up(n, bn)
     ap = _pad2(a, mp, kp)
@@ -685,7 +690,8 @@ def _gated_pallas(a, bg, bu, tile, out_dtype, activation,
     out = _gemm_gated_kernel(ap, bgp, bup, tile=tile,
                              activation=activation, out_dtype=out_dtype,
                              bg_scale=sg, bu_scale=su,
-                             interpret=_interpret())
+                             interpret=_interpret(),
+                             vmem_bytes=vmem_bytes)
     return out[:m, :n]
 
 
@@ -704,29 +710,33 @@ def _dispatch_grouped(pl: GemmPlan, a, b, b_scale, group_sizes, bias
     bias3 = bias.reshape((e, 1, bias.shape[-1])) if bias is not None \
         else None
     if use_pallas():
-        t = pl.tile
-        m, k = a.shape
-        _, _, n = b.shape
-        mp, kp, np_ = round_up(m, t.bm), round_up(k, t.bk), \
-            round_up(n, t.bn)
-        ap = _pad2(a, mp, kp)
-        bp = b if (kp, np_) == (k, n) else jnp.pad(
-            b, ((0, 0), (0, kp - k), (0, np_ - n)))
-        sp = None
-        if b_scale is not None:
-            sp = b_scale if np_ == n else jnp.pad(
-                b_scale, ((0, 0), (0, 0), (0, np_ - n)),
-                constant_values=1.0)
-            sp = sp.astype(jnp.float32)
-        bias_p = None
-        if bias3 is not None:
-            bias_p = bias3 if np_ == n else jnp.pad(
-                bias3, ((0, 0), (0, 0), (0, np_ - n)))
-        out = _gemm_grouped_kernel(ap, bp, sizes, tile=t,
-                                   out_dtype=out_dtype, b_scale=sp,
-                                   bias=bias_p, activation=act,
-                                   interpret=_interpret())
-        return out[:m, :n]
+        def local(a, b, sp, sizes, bias_p):
+            t = _clamp_tile(pl.tile, *a.shape, b.shape[2])
+            m, k = a.shape
+            _, _, n = b.shape
+            mp, kp, np_ = round_up(m, t.bm), round_up(k, t.bk), \
+                round_up(n, t.bn)
+            ap = _pad2(a, mp, kp)
+            bp = b if (kp, np_) == (k, n) else jnp.pad(
+                b, ((0, 0), (0, kp - k), (0, np_ - n)))
+            if sp is not None:
+                sp = sp if np_ == n else jnp.pad(
+                    sp, ((0, 0), (0, 0), (0, np_ - n)),
+                    constant_values=1.0)
+                sp = sp.astype(jnp.float32)
+            if bias_p is not None and np_ != n:
+                bias_p = jnp.pad(bias_p, ((0, 0), (0, 0), (0, np_ - n)))
+            out = _gemm_grouped_kernel(ap, bp, sizes, tile=t,
+                                       out_dtype=out_dtype, b_scale=sp,
+                                       bias=bias_p, activation=act,
+                                       interpret=_interpret(),
+                                       vmem_bytes=pl.vmem_bytes)
+            return out[:m, :n]
+        # rows are ordered by group, so only the columns split
+        bank = (None, None, "model")
+        return shd.per_device(local, (a, b, b_scale, sizes, bias3),
+                              ((None, None), bank, bank, (None,), bank),
+                              (None, "model"))
     return _ref.gemm_grouped_ref(a, b, sizes, b_scale=b_scale,
                                  bias=bias3, activation=act,
                                  out_dtype=out_dtype)
@@ -741,12 +751,23 @@ def _dispatch(pl: GemmPlan, a, b, b_scale, b2, b2_scale, bias, residual,
     act = spec.epilogue.activation
     out_dtype = jnp.dtype(pl.problem.out_dtype)
     if use_pallas():
+        # per device: rows split over the batch axes, columns over
+        # 'model'; the contraction stays whole
+        rows, cols, out = ("batch", None), (None, "model"), \
+            ("batch", "model")
         if spec.gated:
-            return _gated_pallas(a, b, b2, pl.tile, out_dtype, act,
-                                 sg=b_scale, su=b2_scale)
-        return _gemm_pallas(a, b, pl.tile, out_dtype, b_scale=b_scale,
-                            bias=bias, residual=residual,
-                            out_scale=out_scale, activation=act)
+            return shd.per_device(
+                lambda a, bg, bu, sg, su: _gated_pallas(
+                    a, bg, bu, pl.tile, out_dtype, act, pl.vmem_bytes,
+                    sg=sg, su=su),
+                (a, b, b2, b_scale, b2_scale),
+                (rows, cols, cols, cols, cols), out)
+        return shd.per_device(
+            lambda a, b, s, bias, res, osc: _gemm_pallas(
+                a, b, pl.tile, out_dtype, pl.vmem_bytes, b_scale=s,
+                bias=bias, residual=res, out_scale=osc, activation=act),
+            (a, b, b_scale, bias, residual, out_scale),
+            (rows, cols, cols, cols, out, (None, None)), out)
     if spec.gated:
         return _ref.gemm_gated_ref(a, b, b2, activation=act,
                                    bg_scale=b_scale, bu_scale=b2_scale,

@@ -41,8 +41,10 @@ import jax.numpy as jnp
 from repro import telemetry
 from repro.core import bandwidth
 from repro.core.hardware import TPU_V5E, TPUChip
-from repro.core.memory_model import PIPELINE_STAGES, padded_tile_bytes
+from repro.core.memory_model import PIPELINE_STAGES, fits_vmem_bytes, \
+    padded_tile_bytes, vmem_limit_bytes
 from repro.core.tiling import cdiv, dtype_bytes, round_up
+from repro.dist import sharding as shd
 from repro.kernels import ref as _ref
 from repro.kernels.api import TunedInfo, _dtname, _float0, _mode
 from repro.kernels.blocked_attention import attention_blocked
@@ -53,10 +55,6 @@ from repro.kernels.flash_decode import flash_decode, flash_decode_paged
 #: materialize (b, h, sq, skv) scores; the planner switches the XLA
 #: fallback family to the blocked path (moved here from kernels.ops)
 BLOCKED_ATTN_THRESHOLD = 1024
-
-#: fraction of VMEM a flash block choice may claim (matches the GEMM
-#: ``fits_vmem`` headroom for the compiler's own needs)
-VMEM_BUDGET_FRACTION = 0.75
 
 _MODES = ("prefill", "decode", "decode_paged")
 
@@ -367,7 +365,7 @@ def attn_vmem_footprint(p: AttnProblem, kernel: str,
 
 
 def _fits(vmem: AttnVmemFootprint, chip: TPUChip = TPU_V5E) -> bool:
-    return vmem.total <= VMEM_BUDGET_FRACTION * chip.vmem_bytes
+    return fits_vmem_bytes(vmem.total, chip)
 
 
 # ---------------------------------------------------------------------------
@@ -550,10 +548,10 @@ class AttnPlan:
                      + (f" page={self.page_size}" if self.page_size
                         else ""))
         if self.vmem.total:
-            budget = VMEM_BUDGET_FRACTION * TPU_V5E.vmem_bytes
             lines.append(
-                f"  vmem     : {self.vmem.total / mib:.2f} MiB of "
-                f"{budget / mib:.0f} MiB budget "
+                f"  vmem     : {self.vmem.total / mib:.2f} MiB, scoped "
+                f"limit {vmem_limit_bytes(self.vmem.total) / mib:.0f} of "
+                f"{TPU_V5E.vmem_limit_bytes / mib:.0f} MiB "
                 f"(q {self.vmem.q_bytes / mib:.2f}, "
                 f"kv {self.vmem.kv_bytes / mib:.2f}, "
                 f"scratch {self.vmem.scratch_bytes / mib:.2f})")
@@ -819,10 +817,15 @@ def _dispatch_attn(pl: AttnPlan, scale, q_offset, q, k, v, pos,
     spec = pl.spec
     interp = pl.dispatch == "interpret"
     kern = pl.kernel
+    # the Pallas kernels run per device, each on its rows of the batch
+    rows3, rows4 = ("batch", None, None), ("batch", None, None, None)
     if kern == "flash_attention":
-        return flash_attention(
-            q, k, v, causal=spec.causal, window=spec.window, scale=scale,
-            q_offset=q_offset, bq=pl.bq, bkv=pl.bkv, interpret=interp)
+        return shd.per_device(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=spec.causal, window=spec.window,
+                scale=scale, q_offset=q_offset, bq=pl.bq, bkv=pl.bkv,
+                interpret=interp, vmem_bytes=pl.vmem_bytes),
+            (q, k, v), (rows4,) * 3, rows4)
     if kern == "attention_blocked":
         return attention_blocked(
             q, k, v, causal=spec.causal, window=spec.window, scale=scale,
@@ -831,15 +834,26 @@ def _dispatch_attn(pl: AttnPlan, scale, q_offset, q, k, v, pos,
         return _ref.attention_ref(
             q, k, v, causal=spec.causal, window=spec.window, scale=scale,
             q_offset=q_offset)
+    if kern in ("flash_decode", "flash_decode_paged"):
+        # a scalar position reaches every row before the rows split
+        pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (q.shape[0],))
     if kern == "flash_decode":
-        return flash_decode(q, k, v, pos, window=spec.window,
-                            bkv=pl.bkv, scale=scale, interpret=interp)
+        return shd.per_device(
+            lambda q, k, v, pos: flash_decode(
+                q, k, v, pos, window=spec.window, bkv=pl.bkv, scale=scale,
+                interpret=interp, vmem_bytes=pl.vmem_bytes),
+            (q, k, v, pos), (rows3, rows4, rows4, ("batch",)), rows3)
     if kern == "xla_decode":
         return _decode_attention_xla(q, k, v, pos, window=spec.window)
     if kern == "flash_decode_paged":
-        return flash_decode_paged(q, k, v, page_table, pos,
-                                  window=spec.window, scale=scale,
-                                  interpret=interp)
+        # the page pool is whole on every device; rows pick their pages
+        pool = (None,) * 4
+        return shd.per_device(
+            lambda q, k, v, tbl, pos: flash_decode_paged(
+                q, k, v, tbl, pos, window=spec.window, scale=scale,
+                interpret=interp, vmem_bytes=pl.vmem_bytes),
+            (q, k, v, page_table, pos),
+            (rows3, pool, pool, ("batch", None), ("batch",)), rows3)
     if kern == "xla_decode_paged":
         return _decode_attention_paged_xla(q, k, v, page_table, pos,
                                            window=spec.window)

@@ -74,18 +74,21 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "bq", "bkv", "scale", "q_offset", "interpret"))
+    "causal", "window", "bq", "bkv", "scale", "q_offset", "interpret",
+    "vmem_bytes"))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int = 0,
                     scale: float | None = None, q_offset: int | None = None,
                     bq: int = 512, bkv: int = 512,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    vmem_bytes: int = 0) -> jax.Array:
     """q: (b, sq, hq, d); k, v: (b, skv, hkv, d); returns (b, sq, hq, d).
 
     hq % hkv == 0 (GQA: kv head = q head // group, via BlockSpec index
     maps).  d is padded to the 128-lane width inside; sq/skv are padded to
     block multiples (scores for padded kv positions are masked by
-    ``kv_len``).
+    ``kv_len``).  ``vmem_bytes`` (the plan's modeled working set) sets
+    the scoped VMEM limit.
     """
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
@@ -138,7 +141,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((bq, dp), jnp.float32),      # output accumulator
         ],
         compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            ("parallel", "parallel", "arbitrary"), vmem_bytes),
         interpret=interpret,
     )(qt, kt, vt)
 

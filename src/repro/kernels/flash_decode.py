@@ -82,18 +82,20 @@ def _flash_decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "window", "bkv", "scale", "interpret"))
+    "window", "bkv", "scale", "interpret", "vmem_bytes"))
 def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                  pos: jax.Array, *, window: int = 0, bkv: int = 512,
                  scale: float | None = None,
-                 interpret: bool = False) -> jax.Array:
+                 interpret: bool = False,
+                 vmem_bytes: int = 0) -> jax.Array:
     """q: (b, hq, d) one token per slot; caches: (b, S, hkv, d);
     pos: (b,) int32 per-slot positions (a scalar broadcasts — the
     lockstep special case).
 
     Returns (b, hq, d).  Row i masks cache slots > pos[i] (and a sliding
     window when ``window`` > 0 — positions <= pos[i] - window are
-    excluded).
+    excluded).  ``vmem_bytes`` (the plan's modeled working set) sets
+    the scoped VMEM limit.
     """
     b, hq, d = q.shape
     _, skv, hkv, _ = k_cache.shape
@@ -145,8 +147,8 @@ def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, dp), q.dtype),
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(("parallel", "arbitrary"),
+                                         vmem_bytes),
         interpret=interpret,
     )(jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,)), qt, kt, vt)
 
@@ -204,12 +206,13 @@ def _flash_decode_paged_kernel(pos_ref, tbl_ref, q_ref, k_ref, v_ref,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "window", "scale", "interpret"))
+    "window", "scale", "interpret", "vmem_bytes"))
 def flash_decode_paged(q: jax.Array, k_pages: jax.Array,
                        v_pages: jax.Array, page_table: jax.Array,
                        pos: jax.Array, *, window: int = 0,
                        scale: float | None = None,
-                       interpret: bool = False) -> jax.Array:
+                       interpret: bool = False,
+                       vmem_bytes: int = 0) -> jax.Array:
     """Paged flash-decoding: the cache is a shared page pool.
 
     q: (b, hq, d) one token per slot; k_pages/v_pages:
@@ -224,7 +227,8 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array,
     pages a row touches (`ceil((pos+1)/page_size)` of them matter;
     later blocks are masked).  When `page_size == bkv` the block
     accumulation order matches `flash_decode` exactly, so paged and
-    dense outputs are bit-identical.
+    dense outputs are bit-identical.  ``vmem_bytes`` (the plan's
+    modeled working set) sets the scoped VMEM limit.
     """
     b, hq, d = q.shape
     n_pages, ps, hkv, _ = k_pages.shape
@@ -276,8 +280,8 @@ def flash_decode_paged(q: jax.Array, k_pages: jax.Array,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, dp), q.dtype),
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(("parallel", "arbitrary"),
+                                         vmem_bytes),
         interpret=interpret,
     )(jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,)),
       jnp.asarray(page_table, jnp.int32), qt, kt, vt)

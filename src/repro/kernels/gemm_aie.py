@@ -79,14 +79,15 @@ def _gemm_aie_kernel(activation, has_scale, has_bias, has_res, has_oscale,
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "out_dtype",
-                                             "activation", "interpret"))
+                                             "activation", "interpret",
+                                             "vmem_bytes"))
 def gemm_aie(a: jax.Array, b: jax.Array, *, tile: TileConfig,
              out_dtype=None, b_scale: Optional[jax.Array] = None,
              bias: Optional[jax.Array] = None,
              residual: Optional[jax.Array] = None,
              out_scale: Optional[jax.Array] = None,
              activation: Optional[str] = None,
-             interpret: bool = False) -> jax.Array:
+             interpret: bool = False, vmem_bytes: int = 0) -> jax.Array:
     """C[m,n] = epilogue(sum_k A[m,k] B[k,n]), output-stationary.
 
     Dims must be multiples of the tile (ops.py pads — the paper's
@@ -101,6 +102,9 @@ def gemm_aie(a: jax.Array, b: jax.Array, *, tile: TileConfig,
     order: ``bias`` (1, n) add, ``activation`` in fp32, ``residual``
     (m, n) add, ``out_scale`` (1, 1) fp32 output quantization (divide,
     round, clip to [-127, 127]; pair with ``out_dtype=jnp.int8``).
+
+    ``vmem_bytes`` is the plan's modeled VMEM working set; it sets the
+    kernel's scoped VMEM limit.
     """
     m, k = a.shape
     k2, n = b.shape
@@ -148,6 +152,6 @@ def gemm_aie(a: jax.Array, b: jax.Array, *, tile: TileConfig,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), acc)],
         compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            ("parallel", "parallel", "arbitrary"), vmem_bytes),
         interpret=interpret,
     )(*operands)

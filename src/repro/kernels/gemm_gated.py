@@ -64,19 +64,21 @@ def _gated_kernel(activation, has_scale, *refs):
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "out_dtype",
-                                             "activation", "interpret"))
+                                             "activation", "interpret",
+                                             "vmem_bytes"))
 def gemm_gated(a: jax.Array, b_gate: jax.Array, b_up: jax.Array, *,
                tile: TileConfig, activation: str = "silu",
                out_dtype=None,
                bg_scale: Optional[jax.Array] = None,
                bu_scale: Optional[jax.Array] = None,
-               interpret: bool = False) -> jax.Array:
+               interpret: bool = False, vmem_bytes: int = 0) -> jax.Array:
     """C[m,n] = act(A @ B_gate) * (A @ B_up), single resident A stream.
 
     Dims must be multiples of the tile (ops.py pads).  ``bg_scale`` /
     ``bu_scale`` (1, n) fp32 turn on the fused weight-dequant path (both
     B operands must then be int8); scales apply to their accumulators on
-    the flush, before the gate.
+    the flush, before the gate.  ``vmem_bytes`` (the plan's modeled
+    working set) sets the scoped VMEM limit.
     """
     m, k = a.shape
     k2, n = b_gate.shape
@@ -120,6 +122,6 @@ def gemm_gated(a: jax.Array, b_gate: jax.Array, b_up: jax.Array, *,
         scratch_shapes=[pltpu.VMEM((bm, bn), acc),
                         pltpu.VMEM((bm, bn), acc)],
         compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            ("parallel", "parallel", "arbitrary"), vmem_bytes),
         interpret=interpret,
     )(*operands)

@@ -132,13 +132,14 @@ def _grouped_kernel(activation, has_scale, has_bias, bm, bn, *refs):
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "out_dtype",
-                                             "activation", "interpret"))
+                                             "activation", "interpret",
+                                             "vmem_bytes"))
 def gemm_grouped(a: jax.Array, b: jax.Array, group_sizes: jax.Array, *,
                  tile: TileConfig, out_dtype=None,
                  b_scale: Optional[jax.Array] = None,
                  bias: Optional[jax.Array] = None,
                  activation: Optional[str] = None,
-                 interpret: bool = False) -> jax.Array:
+                 interpret: bool = False, vmem_bytes: int = 0) -> jax.Array:
     """``C[r, n] = epilogue(sum_k A[r, k] B[g(r), k, n])`` where ``g(r)``
     is the group owning row ``r`` under ``group_sizes``.
 
@@ -147,6 +148,8 @@ def gemm_grouped(a: jax.Array, b: jax.Array, group_sizes: jax.Array, *,
     ``sum(group_sizes)`` come back zero.  ``b_scale`` (E, 1, n) fp32
     turns on the fused W8A16 dequant (``b`` int8); ``bias`` (E, 1, n) is
     a per-expert bias, applied with ``activation`` on the flush.
+    ``vmem_bytes`` (the plan's modeled working set) sets the scoped VMEM
+    limit.
     """
     m, k = a.shape
     e, k2, n = b.shape
@@ -198,7 +201,7 @@ def gemm_grouped(a: jax.Array, b: jax.Array, group_sizes: jax.Array, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+            ("parallel", "arbitrary", "arbitrary"), vmem_bytes),
         interpret=interpret,
     )(offsets, group_ids, m_tile_ids, *operands)
     # unvisited tail tiles (and straddle rows past the last group) hold

@@ -89,7 +89,8 @@ def _gemm_tb_final_kernel(activation, has_scale, has_bias, has_res,
     o_ref[...] = x.astype(o_ref.dtype)
 
 
-def _tb_call(a, b, c, *, bm: int, bn: int, interpret: bool):
+def _tb_call(a, b, c, *, bm: int, bn: int, interpret: bool,
+             vmem_bytes: int):
     m, k = a.shape
     _, n = b.shape
     grid = (m // bm, n // bn)
@@ -104,14 +105,15 @@ def _tb_call(a, b, c, *, bm: int, bn: int, interpret: bool):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), c.dtype),
         input_output_aliases={2: 0},                      # C updated in place
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(("parallel", "arbitrary"),
+                                         vmem_bytes),
         interpret=interpret,
     )(a, b, c)
 
 
 def _tb_call_final(a, b, c, *, bm: int, bn: int, out_dtype, b_scale,
-                   bias, residual, out_scale, activation, interpret: bool):
+                   bias, residual, out_scale, activation, interpret: bool,
+                   vmem_bytes: int):
     m, k = a.shape
     _, n = b.shape
     grid = (m // bm, n // bn)
@@ -142,8 +144,8 @@ def _tb_call_final(a, b, c, *, bm: int, bn: int, out_dtype, b_scale,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=_compiler_params(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=_compiler_params(("parallel", "arbitrary"),
+                                         vmem_bytes),
         interpret=interpret,
     )(*operands)
 
@@ -171,14 +173,15 @@ def feasible_bk(m: int, k: int, n: int, tile: TileConfig, a_dtype,
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "out_dtype",
-                                             "activation", "interpret"))
+                                             "activation", "interpret",
+                                             "vmem_bytes"))
 def gemm_tb(a: jax.Array, b: jax.Array, *, tile: TileConfig,
             out_dtype=None, b_scale: Optional[jax.Array] = None,
             bias: Optional[jax.Array] = None,
             residual: Optional[jax.Array] = None,
             out_scale: Optional[jax.Array] = None,
             activation: Optional[str] = None,
-            interpret: bool = False) -> jax.Array:
+            interpret: bool = False, vmem_bytes: int = 0) -> jax.Array:
     """C[m,n] = epilogue(sum_k A[m,k] B[k,n]), A-stationary with k-chunked
     PL-style accumulation.  Dims must be tile multiples (ops.py pads).
 
@@ -192,6 +195,9 @@ def gemm_tb(a: jax.Array, b: jax.Array, *, tile: TileConfig,
     (m, n), ``out_scale`` (1, 1) int8 output quantization) fuse into the
     final k-chunk's kernel body — the accumulator is completed and
     post-processed in VMEM, written once at ``out_dtype``.
+
+    ``vmem_bytes`` (the plan's modeled working set) sets the scoped VMEM
+    limit of every k-chunk call.
     """
     m, k = a.shape
     k2, n = b.shape
@@ -232,13 +238,15 @@ def gemm_tb(a: jax.Array, b: jax.Array, *, tile: TileConfig,
     for kk in range(gk - 1):        # k-chunk loop = the paper's V loop
         a_k = jax.lax.slice(a, (0, kk * bk), (m, (kk + 1) * bk))
         b_k = jax.lax.slice(b, (kk * bk, 0), ((kk + 1) * bk, n))
-        c = _tb_call(a_k, b_k, c, bm=bm, bn=bn, interpret=interpret)
+        c = _tb_call(a_k, b_k, c, bm=bm, bn=bn, interpret=interpret,
+                     vmem_bytes=vmem_bytes)
     a_k = jax.lax.slice(a, (0, (gk - 1) * bk), (m, k))
     b_k = jax.lax.slice(b, ((gk - 1) * bk, 0), (k, n))
     if not fused:
-        c = _tb_call(a_k, b_k, c, bm=bm, bn=bn, interpret=interpret)
+        c = _tb_call(a_k, b_k, c, bm=bm, bn=bn, interpret=interpret,
+                     vmem_bytes=vmem_bytes)
         return c.astype(out_dtype)
     return _tb_call_final(a_k, b_k, c, bm=bm, bn=bn, out_dtype=out_dtype,
                           b_scale=b_scale, bias=bias, residual=residual,
                           out_scale=out_scale, activation=activation,
-                          interpret=interpret)
+                          interpret=interpret, vmem_bytes=vmem_bytes)

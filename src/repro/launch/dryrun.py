@@ -139,7 +139,7 @@ def run_cell(arch: str, shape_name: str, mesh_mode: str,
     rec["arg_bytes_per_device"] = _shard_bytes(p.args, p.in_shardings)
     rec["hbm_per_device"] = TPU_V5E.hbm_bytes
 
-    cost = hlo_cost.xla_cost(compiled)
+    cost = compiled.cost_analysis()
     rec["cost_analysis"] = {
         "flops": float(cost.get("flops", 0.0)),
         "bytes_accessed": float(cost.get("bytes accessed", 0.0)),

@@ -28,6 +28,7 @@ from repro.configs.base import get_config, get_smoke_config
 from repro.dist import layout, sharding as shd
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as T
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serve.engine import DecodeEngine, Request
 
 #: prompt lengths a trace draws from — bucketed so the slot-prefill jit
@@ -237,6 +238,7 @@ def main() -> None:
                          "(the paper's int8 x int8 / int32-accumulate "
                          "scheme); implies --int8")
     args = ap.parse_args()
+    print(f"[serve] compile cache: {use_compile_cache()}")
     if args.telemetry:
         telemetry.enable()
     if args.autotune:

@@ -29,6 +29,7 @@ from repro.data import pipeline
 from repro.dist import layout, sharding as shd
 from repro.launch.mesh import make_host_mesh
 from repro.runtime import elastic
+from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.fault_tolerance import StepWatchdog
 from repro.train import train_step as TS
 
@@ -117,6 +118,7 @@ def main() -> None:
                     help="record per-step spans + GEMM plan events and "
                          "write PATH.jsonl + PATH.trace.json")
     args = ap.parse_args()
+    print(f"[train] compile cache: {use_compile_cache()}")
     if args.telemetry:
         telemetry.enable()
     cfg = get_smoke_config(args.arch) if args.smoke \

@@ -335,13 +335,13 @@ def _moe_ffn_ep(params: dict, x: jax.Array, *, top_k: int,
         return y.reshape(b_loc, s_loc, d), aux
 
     batch_spec = batch_axes if batch_axes else None
-    fn = shd.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("model", None, None), P("model", None, None),
                   P("model", None, None), P(),
                   P(batch_spec, "model", None)),
         out_specs=(P(batch_spec, "model", None), P()),
-        check=False)
+        check_vma=False)
     return fn(params["w_gate"], params["w_up"], params["w_down"],
               params["router"], x)
 

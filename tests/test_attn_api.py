@@ -17,6 +17,7 @@ import pytest
 
 from repro import ops
 from repro.core import bandwidth
+from repro.core.memory_model import fits_vmem_bytes
 from repro.kernels import attn_api
 from repro.kernels import ops as legacy
 from repro.kernels import ref as _ref
@@ -436,8 +437,7 @@ def test_solve_topk_is_vmem_feasible_and_ranked():
     ts = [d.traffic.t_model for d in designs]
     assert ts == sorted(ts)
     for d in designs:
-        assert d.vmem.total <= (attn_api.VMEM_BUDGET_FRACTION
-                                * attn_api.TPU_V5E.vmem_bytes)
+        assert fits_vmem_bytes(d.vmem.total, attn_api.TPU_V5E)
 
 
 # ---------------------------------------------------------------------------

@@ -379,7 +379,7 @@ def test_train_swiglu_modeled_hbm_drop_total():
 
 def test_feasible_bk_shrinks_oversized_k_chunk():
     # (2048, 2048) f32 A resident + B streams + rmw C streams: ~112 MiB,
-    # over the 0.75 * 128 MiB budget — the k-chunk must refine
+    # over the 100 MiB scoped limit — the k-chunk must refine
     big = TileConfig(2048, 2048, 2048, "tb")
     p = GemmProblem(2048, 8192, 2048, "float32", "float32")
     assert not fits_vmem(big, p)

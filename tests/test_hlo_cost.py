@@ -34,7 +34,7 @@ class TestFlops:
             return y
         c = _compile(f, *_structs((256, 256), (5, 256, 256)))
         got = hlo_cost.analyze_text(c.as_text()).flops
-        want = hlo_cost.xla_cost(c)["flops"]
+        want = c.cost_analysis()["flops"]
         assert got == pytest.approx(want, rel=0.05)
 
     def test_scan_scales_by_trip_count(self):
@@ -45,7 +45,7 @@ class TestFlops:
         cost = hlo_cost.analyze_text(c.as_text())
         assert cost.flops == pytest.approx(7 * MM, rel=0.01)
         # XLA's own count misses the loop:
-        assert hlo_cost.xla_cost(c)["flops"] == pytest.approx(MM, rel=0.01)
+        assert c.cost_analysis()["flops"] == pytest.approx(MM, rel=0.01)
 
     def test_nested_scan_multiplies(self):
         def inner(c, w):
@@ -85,7 +85,7 @@ class TestBytes:
             return y
         c = _compile(f, *_structs((256, 256), (5, 256, 256)))
         got = hlo_cost.analyze_text(c.as_text()).bytes_accessed
-        want = hlo_cost.xla_cost(c)["bytes accessed"]
+        want = c.cost_analysis()["bytes accessed"]
         assert want * 0.5 <= got <= want * 2.5
 
     def test_scan_weight_reads_not_overcounted(self):
@@ -120,8 +120,8 @@ class TestCollectives:
             y, _ = jax.lax.scan(body, x, None, length=9)
             return y
 
-        g = shd.shard_map(f, mesh=mesh, in_specs=P("x"),
-                          out_specs=P("x"), check=True)
+        g = jax.shard_map(f, mesh=mesh, in_specs=P("x"),
+                          out_specs=P("x"), check_vma=True)
         c = jax.jit(g).lower(
             jax.ShapeDtypeStruct((8, 128), jnp.float32)).compile()
         cost = hlo_cost.analyze_text(c.as_text())

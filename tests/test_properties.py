@@ -11,7 +11,7 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.core import dse, hlo_cost
 from repro.core.hardware import TPU_V5E
-from repro.core.memory_model import vmem_footprint
+from repro.core.memory_model import fits_vmem_bytes, vmem_footprint
 from repro.core.tiling import GemmProblem, TileConfig
 from repro.kernels import ops, ref
 
@@ -30,7 +30,7 @@ def test_dse_always_feasible_and_aligned(m, k, n, dt):
     assert designs
     for d in designs:
         assert d.tile.mxu_aligned(TPU_V5E)
-        assert d.vmem_bytes <= 0.75 * TPU_V5E.vmem_bytes
+        assert fits_vmem_bytes(d.vmem_bytes, TPU_V5E)
         # traffic model sanity: at least compulsory traffic, and padded
         # flops at least the logical flops
         assert d.traffic.hbm_bytes >= p.out_bytes
@@ -57,7 +57,7 @@ def test_dse_mixed_dtype_feasible_for_decode_shapes(m, k, n, a_dt,
     assert designs, (p, strategy)
     best = designs[0]
     assert best.tile.mxu_aligned(TPU_V5E)
-    assert best.vmem_bytes <= 0.75 * TPU_V5E.vmem_bytes
+    assert fits_vmem_bytes(best.vmem_bytes, TPU_V5E)
     uniform = GemmProblem(m, k, n, p.a_dtype, p.out_dtype, p.acc_dtype)
     if p.a_dtype != "int8":                    # genuinely mixed
         u = [d for d in dse.solve(uniform, top=64)
