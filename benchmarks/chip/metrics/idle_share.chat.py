@@ -1,0 +1,14 @@
+"""Share of the traced window in which no operation ran on the device,
+while serving."""
+
+LAYER = "device"
+UNIT = "%"
+MOVES = "ttft_p50_ms"
+SOURCE = "device_trace"
+
+
+def read(rec):
+    tr = rec.get("trace")
+    if not tr or tr["window_s"] <= 0 or not tr["devices"]:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
