@@ -119,17 +119,13 @@ def run_cell(arch: str, shape_name: str, mesh_mode: str,
     rec.update(mesh_shape=list(mesh.devices.shape),
                mesh_axes=list(mesh.axis_names), n_devices=n_devices)
 
-    from repro import telemetry
     with shd.use_mesh(mesh):
         p = specs.build_problem(arch, shape_name, mesh, layout_name)
         rec.update(layout=p.layout_name, tokens_per_step=p.tokens)
         t0 = time.time()
-        with telemetry.span("dryrun.lower", arch=arch, shape=shape_name):
-            lowered = specs.lower_problem(p)
+        lowered = specs.lower_problem(p)
         t1 = time.time()
-        with telemetry.span("dryrun.compile", arch=arch,
-                            shape=shape_name):
-            compiled = lowered.compile()
+        compiled = lowered.compile()
         t2 = time.time()
 
     rec.update(lower_s=round(t1 - t0, 2), compile_s=round(t2 - t1, 2))
@@ -278,8 +274,8 @@ def main() -> None:
                          "flops and report effective per-mode bandwidth/"
                          "compute constants with R2")
     ap.add_argument("--telemetry", default=None, metavar="PATH",
-                    help="record plan events + lower/compile/measure "
-                         "spans; writes PATH.jsonl + PATH.trace.json")
+                    help="record plan events; writes PATH.jsonl + "
+                         "PATH.trace.json")
     ap.add_argument("--layout", default=None,
                     choices=(None, "tp", "fsdp_tp"))
     ap.add_argument("--debug-mesh", default=None,

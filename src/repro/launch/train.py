@@ -84,8 +84,6 @@ def train(cfg, *, steps: int, seq_len: int, global_batch: int,
                 state, metrics = jitted(state, batch)
                 sp.sync(metrics["loss"])
                 jax.block_until_ready(metrics["loss"])
-            telemetry.counter("train.tokens").add(
-                data_cfg.seq_len * data_cfg.global_batch)
             dt = time.time() - t0
             ev = watchdog.observe(step, dt)
             if ev:

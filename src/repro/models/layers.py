@@ -7,6 +7,24 @@ pair of pure functions ``init_*(key, ...) -> params`` and
 planned :func:`repro.ops.gemm` (GemmSpec -> plan -> execute) so the
 paper's tiled-GEMM layer is the compute substrate of every
 architecture.
+
+Every region of a step carries a ``jax.named_scope``, so that each op of
+the compiled program (forward, remat recompute and backward alike) names
+its region in its HLO ``op_name`` and a device trace can be summed by
+region.  The scopes are always on, written as literals where they open:
+
+* ``embed`` — the token embedding and absolute positions (``forward``);
+* ``layers`` — the scan over the stacked layers and the tail layers;
+* ``norm`` — every RMS / layer norm;
+* ``qkv`` — the q/k/v projections with rope, and cross-attention k/v;
+* ``attention`` — the attention kernel call (its custom-VJP backward too);
+* ``attn_out`` — the output projection with its residual epilogue;
+* ``mlp`` — the dense MLP (down projection included) or the MoE FFN;
+* ``lm_head_xent`` — the chunked lm-head softmax cross-entropy;
+* ``grad_clip`` — global-norm gradient clipping;
+* ``optimizer`` — the optimizer update and its learning-rate schedule;
+* ``kv_write`` — the paged K/V pool update (serving);
+* ``sample`` — token sampling from the logits (serving).
 """
 
 from __future__ import annotations
@@ -42,10 +60,11 @@ def init_rms_norm(d: int) -> dict:
 
 
 def rms_norm(params: dict, x: jax.Array, eps: float = 1e-6) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(xf * xf, axis=-1, keepdims=True)
-    out = xf * jax.lax.rsqrt(var + eps) * params["scale"]
-    return out.astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(xf * xf, axis=-1, keepdims=True)
+        out = xf * jax.lax.rsqrt(var + eps) * params["scale"]
+        return out.astype(x.dtype)
 
 
 def init_layer_norm(d: int) -> dict:
@@ -54,12 +73,13 @@ def init_layer_norm(d: int) -> dict:
 
 
 def layer_norm(params: dict, x: jax.Array, eps: float = 1e-5) -> jax.Array:
-    xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    out = (xf - mean) * jax.lax.rsqrt(var + eps) * params["scale"] \
-        + params["bias"]
-    return out.astype(x.dtype)
+    with jax.named_scope("norm"):
+        xf = x.astype(jnp.float32)
+        mean = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.var(xf, axis=-1, keepdims=True)
+        out = (xf - mean) * jax.lax.rsqrt(var + eps) * params["scale"] \
+            + params["bias"]
+        return out.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -153,26 +173,29 @@ def init_attention(key, spec: AttnLayerSpec, dtype) -> dict:
 
 def _project_qkv(params, x, spec: AttnLayerSpec, positions):
     b, s, _ = x.shape
-    q = ops.gemm(x, params["wq"]).reshape(b, s, spec.n_heads, spec.head_dim)
-    k = ops.gemm(x, params["wk"]).reshape(b, s, spec.n_kv_heads,
-                                          spec.head_dim)
-    v = ops.gemm(x, params["wv"]).reshape(b, s, spec.n_kv_heads,
-                                          spec.head_dim)
-    if spec.use_rope:
-        q = rope(q, positions, spec.rope_theta)
-        k = rope(k, positions, spec.rope_theta)
-    return q, k, v
+    with jax.named_scope("qkv"):
+        q = ops.gemm(x, params["wq"]).reshape(b, s, spec.n_heads,
+                                              spec.head_dim)
+        k = ops.gemm(x, params["wk"]).reshape(b, s, spec.n_kv_heads,
+                                              spec.head_dim)
+        v = ops.gemm(x, params["wv"]).reshape(b, s, spec.n_kv_heads,
+                                              spec.head_dim)
+        if spec.use_rope:
+            q = rope(q, positions, spec.rope_theta)
+            k = rope(k, positions, spec.rope_theta)
+        return q, k, v
 
 
 def project_kv(params: dict, memory: jax.Array, spec: AttnLayerSpec
                ) -> Tuple[jax.Array, jax.Array]:
     """Project cross-attention k/v heads from raw encoder memory."""
     b, f, _ = memory.shape
-    k = ops.gemm(memory, params["wk"]).reshape(b, f, spec.n_kv_heads,
-                                               spec.head_dim)
-    v = ops.gemm(memory, params["wv"]).reshape(b, f, spec.n_kv_heads,
-                                               spec.head_dim)
-    return k, v
+    with jax.named_scope("qkv"):
+        k = ops.gemm(memory, params["wk"]).reshape(b, f, spec.n_kv_heads,
+                                                   spec.head_dim)
+        v = ops.gemm(memory, params["wv"]).reshape(b, f, spec.n_kv_heads,
+                                                   spec.head_dim)
+        return k, v
 
 
 def attention_block(params: dict, x: jax.Array, spec: AttnLayerSpec,
@@ -194,20 +217,24 @@ def attention_block(params: dict, x: jax.Array, spec: AttnLayerSpec,
         positions = jnp.arange(s)
     if kv is None and memory is None:
         q, k, v = _project_qkv(params, x, spec, positions)
-        out = ops.attention(q, k, v, causal=spec.causal,
-                            window=spec.window)
+        with jax.named_scope("attention"):
+            out = ops.attention(q, k, v, causal=spec.causal,
+                                window=spec.window)
     else:
-        q = ops.gemm(x, params["wq"]).reshape(b, s, spec.n_heads,
-                                              spec.head_dim)
-        if spec.use_rope:
-            q = rope(q, positions, spec.rope_theta)
+        with jax.named_scope("qkv"):
+            q = ops.gemm(x, params["wq"]).reshape(b, s, spec.n_heads,
+                                                  spec.head_dim)
+            if spec.use_rope:
+                q = rope(q, positions, spec.rope_theta)
         if kv is None:
             kv = project_kv(params, memory, spec)
         k, v = kv
-        out = ops.attention(q, k, v, causal=False, window=0)
+        with jax.named_scope("attention"):
+            out = ops.attention(q, k, v, causal=False, window=0)
     out = shd.act(out, ("batch", None, "model", None))
-    return ops.gemm(out.reshape(b, s, -1), params["wo"],
-                    residual=residual)
+    with jax.named_scope("attn_out"):
+        return ops.gemm(out.reshape(b, s, -1), params["wo"],
+                        residual=residual)
 
 
 def init_kv_cache(batch: int, max_len: int, spec: AttnLayerSpec, dtype) -> dict:
@@ -286,17 +313,20 @@ def paged_attention_decode(params: dict, x: jax.Array, cache: dict,
     # indexes in-bounds (it lands on the sink page regardless)
     pages = page_table[rows, jnp.minimum(pos // ps, max_pages - 1)]
     offs = pos % ps
-    k_cache = cache["k"].at[pages, offs].set(
-        k_new[:, 0].astype(cache["k"].dtype))
-    v_cache = cache["v"].at[pages, offs].set(
-        v_new[:, 0].astype(cache["v"].dtype))
-    # same CPU-XLA bf16-hoisting workaround as the dense path
-    k_att, v_att = jax.lax.optimization_barrier((k_cache, v_cache))
+    with jax.named_scope("kv_write"):
+        k_cache = cache["k"].at[pages, offs].set(
+            k_new[:, 0].astype(cache["k"].dtype))
+        v_cache = cache["v"].at[pages, offs].set(
+            v_new[:, 0].astype(cache["v"].dtype))
+        # same CPU-XLA bf16-hoisting workaround as the dense path
+        k_att, v_att = jax.lax.optimization_barrier((k_cache, v_cache))
 
-    out = ops.decode_attention_paged(q[:, 0], k_att, v_att, page_table,
-                                     pos, window=spec.window)
-    out = ops.gemm(out.reshape(b, 1, -1), params["wo"],
-                   residual=residual)
+    with jax.named_scope("attention"):
+        out = ops.decode_attention_paged(q[:, 0], k_att, v_att, page_table,
+                                         pos, window=spec.window)
+    with jax.named_scope("attn_out"):
+        out = ops.gemm(out.reshape(b, 1, -1), params["wo"],
+                       residual=residual)
     return out, {"k": k_cache, "v": v_cache}
 
 

@@ -221,24 +221,25 @@ def moe_ffn(params: dict, x: jax.Array, *, top_k: int,
     Dispatches to the shard_map EP path when a mesh with a non-trivial
     'model' axis is active and shapes divide; else the pjit path.
     """
-    mesh = shd.current_mesh()
-    n_experts = params["router"].shape[-1]
-    if mesh is not None and ep_enabled():
-        sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-        m = sizes.get("model", 1)
-        batch_axes = tuple(a for a in ("pod", "data")
-                           if a in mesh.axis_names)
-        bsz = 1
-        for a in batch_axes:
-            bsz *= sizes[a]
-        b, s, _ = x.shape
-        if (m > 1 and n_experts % m == 0 and b % bsz == 0
-                and s % m == 0 and (b // bsz) * (s // m) >= 1):
-            return _moe_ffn_ep(params, x, top_k=top_k,
-                               capacity_factor=capacity_factor,
-                               mesh=mesh, batch_axes=batch_axes)
-    return _moe_ffn_pjit(params, x, top_k=top_k,
-                         capacity_factor=capacity_factor)
+    with jax.named_scope("mlp"):
+        mesh = shd.current_mesh()
+        n_experts = params["router"].shape[-1]
+        if mesh is not None and ep_enabled():
+            sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+            m = sizes.get("model", 1)
+            batch_axes = tuple(a for a in ("pod", "data")
+                               if a in mesh.axis_names)
+            bsz = 1
+            for a in batch_axes:
+                bsz *= sizes[a]
+            b, s, _ = x.shape
+            if (m > 1 and n_experts % m == 0 and b % bsz == 0
+                    and s % m == 0 and (b // bsz) * (s // m) >= 1):
+                return _moe_ffn_ep(params, x, top_k=top_k,
+                                   capacity_factor=capacity_factor,
+                                   mesh=mesh, batch_axes=batch_axes)
+        return _moe_ffn_pjit(params, x, top_k=top_k,
+                             capacity_factor=capacity_factor)
 
 
 def _ep_grouped_gemms(params: dict, recv: jax.Array, sz: jax.Array,

@@ -92,9 +92,10 @@ def _mlp_init(key, cfg: ModelConfig):
 def _mlp(cfg: ModelConfig, p, x, residual=None):
     """MLP through the fused kernels; ``residual`` rides the down
     projection's epilogue (one C write instead of GEMM + XLA add)."""
-    if cfg.family == "audio":
-        return L.gelu_mlp(p, x, residual=residual)
-    return L.swiglu(p, x, residual=residual)
+    with jax.named_scope("mlp"):
+        if cfg.family == "audio":
+            return L.gelu_mlp(p, x, residual=residual)
+        return L.swiglu(p, x, residual=residual)
 
 
 def init_layer(key, cfg: ModelConfig, kind: str) -> dict:
@@ -225,10 +226,11 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
     ``prefix_embeds`` (vlm): (b, P, d) prepended to token embeddings.
     ``frames`` (audio): (b, F, d) stub encoder input.
     """
-    x = L.embed(params["embed"], tokens)
-    if prefix_embeds is not None:
-        x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
-    x = _maybe_abs_pos(cfg, x, 0)
+    with jax.named_scope("embed"):
+        x = L.embed(params["embed"], tokens)
+        if prefix_embeds is not None:
+            x = jnp.concatenate([prefix_embeds.astype(x.dtype), x], axis=1)
+        x = _maybe_abs_pos(cfg, x, 0)
     # 'seq' resolves to 'model' under sequence parallelism (the stored
     # remat carry is then 1/|model| per device), else to None
     x = shd.act(x, ("batch", "seq", None))
@@ -246,12 +248,13 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
         return (h, aux), None
 
     fn = jax.checkpoint(unit) if remat else unit
-    (x, aux), _ = jax.lax.scan(fn, (x, jnp.zeros((), jnp.float32)),
-                               params["layers"])
-    for i, kind in enumerate(cfg.tail_pattern):
-        x, a = apply_layer(params["tail"][f"t{i}"], cfg, kind, x,
-                           enc_out=enc_out)
-        aux = aux + a
+    with jax.named_scope("layers"):
+        (x, aux), _ = jax.lax.scan(fn, (x, jnp.zeros((), jnp.float32)),
+                                   params["layers"])
+        for i, kind in enumerate(cfg.tail_pattern):
+            x, a = apply_layer(params["tail"][f"t{i}"], cfg, kind, x,
+                               enc_out=enc_out)
+            aux = aux + a
     return _norm(cfg, params["final_norm"], x), aux
 
 
@@ -267,8 +270,9 @@ def loss_fn(params: dict, cfg: ModelConfig, batch: dict, *,
     if batch.get("prefix_embeds") is not None:
         p = batch["prefix_embeds"].shape[1]
         h = h[:, p:]                       # loss over text positions only
-    ce = L.chunked_softmax_xent(h, params["lm_head"], labels,
-                                n_chunks=n_chunks, label_mask=mask)
+    with jax.named_scope("lm_head_xent"):
+        ce = L.chunked_softmax_xent(h, params["lm_head"], labels,
+                                    n_chunks=n_chunks, label_mask=mask)
     loss = ce + AUX_LOSS_WEIGHT * aux
     return loss, {"ce": ce, "aux": aux}
 
@@ -723,23 +727,26 @@ def _prefill_chunk_layer(p: dict, cache: dict, cfg: ModelConfig,
     positions = jnp.arange(start, start + s)
     q, k, v = L._project_qkv(p["attn"], h, spec, positions)
     ps = cache["k"].shape[1]
-    pages = table_row[jnp.asarray(
-        [(start + j) // ps for j in range(s)])]
-    offs = jnp.asarray([(start + j) % ps for j in range(s)],
-                       jnp.int32)
-    ck = cache["k"].at[pages, offs].set(k[0].astype(cache["k"].dtype))
-    cv = cache["v"].at[pages, offs].set(v[0].astype(cache["v"].dtype))
-    # same CPU-XLA bf16-hoisting workaround as attention_decode
-    ckb, cvb = jax.lax.optimization_barrier((ck, cv))
-    n_hist = -(-(start + s) // ps)            # pages holding history
-    hist = table_row[:n_hist]
-    kf = ckb[hist].reshape(1, n_hist * ps, spec.n_kv_heads,
-                           spec.head_dim)[:, :start + s]
-    vf = cvb[hist].reshape(1, n_hist * ps, spec.n_kv_heads,
-                           spec.head_dim)[:, :start + s]
-    out = ops.attention(q, kf, vf, causal=True, window=spec.window,
-                        q_offset=start)
-    x = ops.gemm(out.reshape(b, s, -1), p["attn"]["wo"], residual=x)
+    with jax.named_scope("kv_write"):
+        pages = table_row[jnp.asarray(
+            [(start + j) // ps for j in range(s)])]
+        offs = jnp.asarray([(start + j) % ps for j in range(s)],
+                           jnp.int32)
+        ck = cache["k"].at[pages, offs].set(k[0].astype(cache["k"].dtype))
+        cv = cache["v"].at[pages, offs].set(v[0].astype(cache["v"].dtype))
+        # same CPU-XLA bf16-hoisting workaround as attention_decode
+        ckb, cvb = jax.lax.optimization_barrier((ck, cv))
+    with jax.named_scope("attention"):
+        n_hist = -(-(start + s) // ps)            # pages holding history
+        hist = table_row[:n_hist]
+        kf = ckb[hist].reshape(1, n_hist * ps, spec.n_kv_heads,
+                               spec.head_dim)[:, :start + s]
+        vf = cvb[hist].reshape(1, n_hist * ps, spec.n_kv_heads,
+                               spec.head_dim)[:, :start + s]
+        out = ops.attention(q, kf, vf, causal=True, window=spec.window,
+                            q_offset=start)
+    with jax.named_scope("attn_out"):
+        x = ops.gemm(out.reshape(b, s, -1), p["attn"]["wo"], residual=x)
     cache = {"k": ck, "v": cv}
     hh = _norm(cfg, p["norm2"], x)
     if kind == "moe":

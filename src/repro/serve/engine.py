@@ -268,8 +268,7 @@ class DecodeEngine:
         self._step = jax.jit(
             lambda p, tok, cache: T.decode_step(p, cfg, tok, cache),
             donate_argnums=(2,))
-        self._argmax = jax.jit(
-            lambda lg: jnp.argmax(lg, axis=-1).astype(jnp.int32))
+        self._argmax = jax.jit(self._argmax_impl)
         self._sample_temp = jax.jit(self._sample_temp_impl)
 
         self.kv: Optional[paging.PagedKV] = None
@@ -309,13 +308,19 @@ class DecodeEngine:
     # ------------------------------------------------------------ sampling
 
     @staticmethod
+    def _argmax_impl(logits):
+        with jax.named_scope("sample"):
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+    @staticmethod
     def _sample_temp_impl(logits, key, temps):
         """Per-slot sampling: greedy rows where temperature == 0,
         categorical at ``logits / temp`` elsewhere — one batched op."""
-        greedy = jnp.argmax(logits, axis=-1)
-        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-        sampled = jax.random.categorical(key, scaled, axis=-1)
-        return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            greedy = jnp.argmax(logits, axis=-1)
+            scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+            sampled = jax.random.categorical(key, scaled, axis=-1)
+            return jnp.where(temps > 0, sampled, greedy).astype(jnp.int32)
 
     def _sample(self, logits: jax.Array, temps: np.ndarray) -> jax.Array:
         """logits: (n, V) -> (n,) int32 tokens."""

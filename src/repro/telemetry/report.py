@@ -98,7 +98,9 @@ def model_vs_measured(plans: Optional[Sequence] = None, *,
             dt = meas.median_s
             row["t_measured_us"] = round(dt * 1e6, 2)
             row["spread"] = round(meas.spread, 4)
-            row["achieved"] = round(pl.traffic.t_model / dt, 5)
+            # significant figures: on a slow host the fraction is under
+            # 1e-4, where a fixed number of decimals keeps one digit
+            row["achieved"] = float(f"{pl.traffic.t_model / dt:.4g}")
             if meas.rejected:
                 row["note"] = f"{meas.rejected} outlier(s) rejected"
             telemetry.event("gemm.measured", **{
@@ -113,12 +115,13 @@ def model_vs_measured(plans: Optional[Sequence] = None, *,
 def summarize(rows: Sequence[dict]) -> dict:
     measured = [r for r in rows if r["t_measured_us"] is not None]
     skipped = len(rows) - len(measured)
+    mean = (float(f"{np.mean([r['achieved'] for r in measured]):.4g}")
+            if measured else None)
     return {
         "n_plans": len(rows),
         "n_measured": len(measured),
         "n_skipped": skipped,
-        "mean_achieved": (round(float(np.mean(
-            [r["achieved"] for r in measured])), 5) if measured else None),
+        "mean_achieved": mean,
     }
 
 

@@ -14,10 +14,14 @@ This module is that layer:
   can register arrays via ``sp.sync(x)`` and its exit calls
   ``jax.block_until_ready`` on them — the device time is billed to the
   span that launched it, not to whichever later host line happens to
-  block.
+  block.  An enabled span also holds a ``jax.profiler.TraceAnnotation``
+  of its name, so under a profiler session it lands on the host plane
+  of the ``.xplane.pb``, on the same clock as the device ops.
 * :func:`event` / :func:`complete_span` — instant events and
   retroactively-timed spans (for lifecycles that cross host loop
-  iterations, e.g. one serve request from queued to finished).
+  iterations, e.g. one serve request from queued to finished).  A
+  retroactive span has no profiler annotation: it is only in the
+  JSONL / Chrome-trace export.
 * :func:`counter` / :func:`gauge` — typed metric registries.  Counters
   accumulate (snapshot-only); every gauge ``set`` also records a
   timeline sample, which the Chrome-trace export renders as a counter
@@ -42,6 +46,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 __all__ = [
     "Counter", "Gauge", "Recorder", "Span", "SCHEMA_VERSION",
@@ -146,10 +152,11 @@ class Span:
     """One live hierarchical span.  Use as a context manager; ``set``
     attaches attributes, ``sync(x)`` registers a jax value to
     ``block_until_ready`` at exit (so asynchronously dispatched device
-    work is billed to this span)."""
+    work is billed to this span).  While open it holds a profiler
+    annotation of its name."""
 
     __slots__ = ("name", "attrs", "_rec", "_t0", "_t1", "_syncs",
-                 "sid", "parent", "depth")
+                 "sid", "parent", "depth", "_ann")
 
     def __init__(self, rec: "Recorder", name: str, attrs: Dict[str, Any]):
         self.name = name
@@ -174,6 +181,8 @@ class Span:
         self.depth = len(st)
         self.sid = self._rec._new_sid()
         st.append(self)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -183,6 +192,7 @@ class Span:
             jax.block_until_ready(self._syncs)
             self._syncs = []
         self._t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
         self._rec._pop(self)
         return False
 

@@ -106,14 +106,12 @@ def make_train_step(cfg: ModelConfig, *, optimizer: Optional[str] = None,
 
     def train_step(state: TrainState, batch) -> Tuple[TrainState, dict]:
         loss, metrics, grads = grads_of(state.params, batch)
-        grads, gnorm = clip_by_global_norm(grads, grad_clip)
-        lr = sched.warmup_cosine(state.step, peak_lr=peak_lr,
-                                 warmup_steps=warmup_steps,
-                                 total_steps=total_steps)
-        if optimizer == "adamw":
-            params, opt = opt_update(grads, state.opt, state.params,
-                                     lr=lr, weight_decay=weight_decay)
-        else:
+        with jax.named_scope("grad_clip"):
+            grads, gnorm = clip_by_global_norm(grads, grad_clip)
+        with jax.named_scope("optimizer"):
+            lr = sched.warmup_cosine(state.step, peak_lr=peak_lr,
+                                     warmup_steps=warmup_steps,
+                                     total_steps=total_steps)
             params, opt = opt_update(grads, state.opt, state.params,
                                      lr=lr, weight_decay=weight_decay)
         new_state = TrainState(params=params, opt=opt,

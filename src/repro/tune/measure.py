@@ -33,8 +33,6 @@ from typing import Callable, Optional, Tuple
 
 import numpy as np
 
-from repro import telemetry
-
 #: per-GEMM flop budget for measured passes — dryrun plan caches contain
 #: million-token train GEMMs that would take hours on a CPU host
 DEFAULT_MAX_FLOPS = 5e10
@@ -156,15 +154,10 @@ def measure_plan(pl, *, iters: int = DEFAULT_ITERS,
     for _ in range(max(1, warmup)):          # compile + warm-up
         jax.block_until_ready(jitted(*args))
     times = []
-    with telemetry.span("measure.gemm", spec=pl.spec.key,
-                        m=pl.m, k=pl.k, n=pl.n, iters=iters,
-                        warmup=warmup) as sp:
-        for _ in range(max(1, iters)):
-            t0 = timer()
-            out = jitted(*args)
-            jax.block_until_ready(out)
-            times.append(timer() - t0)
-        sp.sync(out)
+    for _ in range(max(1, iters)):
+        t0 = timer()
+        jax.block_until_ready(jitted(*args))
+        times.append(timer() - t0)
     return Measurement(times_s=tuple(times),
                        kept_s=reject_outliers(tuple(times)),
                        warmup=max(1, warmup))
@@ -220,15 +213,10 @@ def measure_attn_plan(pl, *, iters: int = DEFAULT_ITERS,
     for _ in range(max(1, warmup)):          # compile + warm-up
         jax.block_until_ready(jitted(*args))
     times = []
-    with telemetry.span("measure.attn", spec=pl.spec.key,
-                        shape=pl.shape_key, kernel=pl.kernel,
-                        iters=iters, warmup=warmup) as sp:
-        for _ in range(max(1, iters)):
-            t0 = timer()
-            out = jitted(*args)
-            jax.block_until_ready(out)
-            times.append(timer() - t0)
-        sp.sync(out)
+    for _ in range(max(1, iters)):
+        t0 = timer()
+        jax.block_until_ready(jitted(*args))
+        times.append(timer() - t0)
     return Measurement(times_s=tuple(times),
                        kept_s=reject_outliers(tuple(times)),
                        warmup=max(1, warmup))

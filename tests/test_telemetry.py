@@ -126,6 +126,37 @@ def test_disabled_mode_is_allocation_free_noop():
     assert telemetry.export("/nonexistent/should-not-write") is None
 
 
+def test_disabled_span_is_the_shared_noop_and_annotates_nothing():
+    from repro.telemetry import telemetry as tmod
+    sp = telemetry.span("a")
+    assert sp is tmod._NOOP_SPAN and type(sp).__slots__ == ()
+    with sp as inner:
+        assert inner is sp
+
+
+# ----------------------------------------------------- profiler annotation
+
+def _xplane_events(log_dir):
+    path = next(pathlib.Path(log_dir).rglob("*.xplane.pb"))
+    prof = jax.profiler.ProfileData.from_file(str(path))
+    return [(plane.name, ev.name) for plane in prof.planes
+            for line in plane.lines for ev in line.events]
+
+
+def test_enabled_span_lands_on_the_profiler_host_plane(rec, tmp_path):
+    with jax.profiler.trace(str(tmp_path)):
+        with telemetry.span("probe.annotated"):
+            jax.block_until_ready(jax.numpy.ones(4) + 1)
+        telemetry.complete_span("probe.retroactive", 0.0, 1.0)
+    events = _xplane_events(tmp_path)
+    hosts = {plane for plane, name in events if name == "probe.annotated"}
+    assert hosts and all(h.startswith("/host:") for h in hosts)
+    # a retroactive span is only in the recorder's own export
+    assert all(name != "probe.retroactive" for _, name in events)
+    assert {e["name"] for e in rec.events} >= {"probe.annotated",
+                                               "probe.retroactive"}
+
+
 # -------------------------------------------------------------- exports
 
 def test_jsonl_roundtrip_schema(rec, tmp_path):
