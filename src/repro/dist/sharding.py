@@ -175,9 +175,10 @@ def per_device(fn: Callable, args: Sequence, axes: Sequence, out_axes):
     multi-device mesh the call goes through ``jax.shard_map``.  Each
     ``axes`` entry gives an argument's logical axes as for :func:`act`
     (``None`` arguments pass through untouched); ``out_axes`` gives the
-    result's.  A dim named by no logical axis, or one its mesh axes do
-    not divide, is whole on every device, so the logical axes may only
-    split dims that ``fn`` treats independently.  Outside a mesh, on one
+    result's, or a tuple of them for a tuple of results.  A dim named by
+    no logical axis, or one its mesh axes do not divide, is whole on
+    every device, so the logical axes may only split dims that ``fn``
+    treats independently.  Outside a mesh, on one
     device, or already inside a ``shard_map``, ``fn`` runs as is.
     """
     mesh = current_mesh()
@@ -198,7 +199,10 @@ def per_device(fn: Callable, args: Sequence, axes: Sequence, out_axes):
         local, mesh=mesh,
         in_specs=tuple(logical_spec(args[i].shape, axes[i], sizes)
                        for i in live),
-        out_specs=logical_spec(out.shape, out_axes, sizes),
+        out_specs=(tuple(logical_spec(o.shape, a, sizes)
+                         for o, a in zip(out, out_axes))
+                   if isinstance(out, tuple)
+                   else logical_spec(out.shape, out_axes, sizes)),
         check_vma=False)(*(args[i] for i in live))
 
 

@@ -13,8 +13,11 @@ sizes, chosen from the same :mod:`repro.core.memory_model` VMEM-fit and
 (decode KV streams billed at per-row true positions and page-rounded
 pool reads via :func:`repro.core.bandwidth.decode_kv_bytes`); and
 :func:`attn_execute` runs the plan through ONE generic
-``jax.custom_vjp`` whose backward recomputes through the differentiable
-reference composition — the Pallas flash kernels stay forward-only.
+``jax.custom_vjp``.  Its backward is the plan's ``bwd_kernel``: a
+``flash_attention`` plan takes the Pallas FlashAttention-2 backward
+(:func:`~repro.kernels.flash_attention.flash_attention_bwd`), fed by
+the log-sum-exp its forward rule saves; every other family recomputes
+through its differentiable XLA composition.
 
 Plans are cached per (spec, shape, dispatch mode) with hit/miss
 counters, emit ``attn.plan`` telemetry events with the full modeled
@@ -48,7 +51,8 @@ from repro.dist import sharding as shd
 from repro.kernels import ref as _ref
 from repro.kernels.api import TunedInfo, _dtname, _float0, _mode
 from repro.kernels.blocked_attention import attention_blocked
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import flash_attention, \
+    flash_attention_bwd, kv_block_range
 from repro.kernels.flash_decode import flash_decode, flash_decode_paged
 
 #: above this many query/kv positions the unblocked reference would
@@ -344,10 +348,30 @@ def attn_vmem_footprint(p: AttnProblem, kernel: str,
     """Double-buffered q/k/v streams + the online-softmax scratch
     ((rows, lane) running max/denominator pair and the fp32
     accumulator), via the same ``padded_tile_bytes`` physical-padding
-    rule the GEMM footprint uses."""
+    rule the GEMM footprint uses.
+
+    The backward kernels (``flash_attention_bwd_dkv`` / ``_dq``) stream
+    q, dO and the lse and D rows on the q side, k and v on the kv side,
+    write double-buffered dK+dV or dQ blocks, and hold their f32
+    accumulators plus the (bq, bkv) f32 score, probability, dP and dS
+    temporaries and one cast of them."""
     if kernel.startswith("xla"):
         return AttnVmemFootprint(0, 0, 0, 0)
     dp = round_up(p.d, chip.lane)
+    if kernel.startswith("flash_attention_bwd"):
+        def tile(r, c, dt):
+            return padded_tile_bytes(r, c, dt, chip)
+        q = PIPELINE_STAGES * (2 * tile(bq, dp, p.q_dtype)
+                               + 2 * tile(1, bq, "float32"))
+        kv = 2 * PIPELINE_STAGES * tile(bkv, dp, p.kv_dtype)
+        temps = 4 * tile(bq, bkv, "float32") + tile(bq, bkv, p.q_dtype)
+        if kernel.endswith("dkv"):
+            o = 2 * PIPELINE_STAGES * tile(bkv, dp, p.kv_dtype)
+            acc = 2 * tile(bkv, dp, "float32")
+        else:
+            o = PIPELINE_STAGES * tile(bq, dp, p.q_dtype)
+            acc = tile(bq, dp, "float32")
+        return AttnVmemFootprint(q, kv, o, acc + temps)
     if kernel in ("flash_attention", "attention_blocked"):
         rows = bq or min(p.sq, 512)
         kv_rows = bkv or min(p.skv, 512)
@@ -366,6 +390,76 @@ def attn_vmem_footprint(p: AttnProblem, kernel: str,
 
 def _fits(vmem: AttnVmemFootprint, chip: TPUChip = TPU_V5E) -> bool:
     return fits_vmem_bytes(vmem.total, chip)
+
+
+#: the two kernels of the Pallas backward, each with its own working set
+_BWD_KERNELS = ("flash_attention_bwd_dkv", "flash_attention_bwd_dq")
+
+#: backward blocks in order of preference; the first whose two kernels
+#: fit VMEM is taken (clamped like the forward's)
+_BWD_BLOCKS = ((512, 512), (256, 256), (128, 128))
+
+
+class AttnBackward(NamedTuple):
+    """How a plan's VJP computes its backward: the kernel family, and for
+    the Pallas backward its blocks, the (q, kv) blocks it computes of the
+    whole grid, and the two kernels' VMEM working sets."""
+
+    kernel: str
+    bq: Optional[int] = None
+    bkv: Optional[int] = None
+    blocks: Tuple[int, int] = (0, 0)          # (computed, of all)
+    vmem: Tuple[int, ...] = ()                # dK/dV, dQ totals
+
+    @property
+    def block_share(self) -> Optional[float]:
+        return self.blocks[0] / self.blocks[1] if self.blocks[1] else None
+
+
+def _recompute_family(spec: AttnSpec, p: AttnProblem) -> str:
+    """The differentiable XLA composition a backward recomputes through
+    when it has no Pallas kernel: blocked for long prefill (no (sq, skv)
+    scores), the plain reference for short, the decode einsums."""
+    if spec.mode == "decode":
+        return "xla_decode"
+    if spec.mode == "decode_paged":
+        return "xla_decode_paged"
+    return ("attention_blocked"
+            if max(p.sq, p.skv) > BLOCKED_ATTN_THRESHOLD else "xla_ref")
+
+
+def _computed_blocks(p: AttnProblem, bq: int, bkv: int) -> Tuple[int, int]:
+    """(q, kv) blocks the backward computes, of all: those the causal
+    diagonal and the window leave a key in (the kernels' own ranges)."""
+    nq, nkv = cdiv(p.sq, bq), cdiv(p.skv, bkv)
+    live = 0
+    for qi in range(nq):
+        lo, hi = kv_block_range(qi, bq=bq, bkv=bkv, nkv=nkv,
+                                causal=p.causal, window=p.window,
+                                q_offset=p.skv - p.sq)
+        live += max(0, hi - lo + 1)
+    return live, nq * nkv
+
+
+def _plan_backward(spec: AttnSpec, p: AttnProblem, kernel: str,
+                   bq: Optional[int]) -> AttnBackward:
+    """The Pallas backward for a ``flash_attention`` plan whose forward
+    can write its lse rows (q block a multiple of the lane width), with
+    the first preferred blocks that fit VMEM, or the explicit blocks of
+    the spec; every other plan recomputes."""
+    if kernel != "flash_attention" or bq % TPU_V5E.lane:
+        return AttnBackward(_recompute_family(spec, p))
+    bq_cap, bkv_cap = _pow2_cap(p.sq, 8), _pow2_cap(p.skv, 128)
+    cands = ([(spec.bq or 512, spec.bkv or 512)]
+             if spec.bq or spec.bkv else _BWD_BLOCKS)
+    for cq, ckv in cands:
+        cq, ckv = min(cq, bq_cap), min(ckv, bkv_cap)
+        vmem = tuple(attn_vmem_footprint(p, k, cq, ckv).total
+                     for k in _BWD_KERNELS)
+        if all(fits_vmem_bytes(v) for v in vmem):
+            return AttnBackward("flash_attention_bwd", cq, ckv,
+                                _computed_blocks(p, cq, ckv), vmem)
+    return AttnBackward(_recompute_family(spec, p))
 
 
 # ---------------------------------------------------------------------------
@@ -489,8 +583,13 @@ class AttnPlan:
     problem: AttnProblem
     traffic: bandwidth.TrafficEstimate
     vmem: AttnVmemFootprint
+    bwd: AttnBackward
     fallback_reason: Optional[str] = None
     tuned: Optional[TunedInfo] = None
+
+    @property
+    def bwd_kernel(self) -> str:
+        return self.bwd.kernel
 
     @property
     def flops(self) -> float:
@@ -579,6 +678,15 @@ class AttnPlan:
                 f" ±{tu.spread:.2f}, {src})")
         else:
             lines.append("  source   : analytic")
+        bw = self.bwd
+        if bw.bq is not None:
+            lines.append(
+                f"  backward : {bw.kernel} bq={bw.bq} bkv={bw.bkv}, "
+                f"{bw.blocks[0]} of {bw.blocks[1]} blocks computed "
+                f"({100 * bw.block_share:.1f}%), vmem dK/dV "
+                f"{bw.vmem[0] / mib:.2f} MiB, dQ {bw.vmem[1] / mib:.2f} MiB")
+        else:
+            lines.append(f"  backward : {bw.kernel} (recompute)")
         if self.fallback_reason:
             lines.append(f"  fallback : {self.fallback_reason}")
         return "\n".join(lines)
@@ -737,7 +845,8 @@ def _resolve(spec: AttnSpec, shapes: Tuple[int, ...]) -> AttnPlan:
         hkv=f["hkv"], d=f["d"], page_size=f["page_size"],
         max_pages=f["max_pages"], dispatch=dispatch, kernel=kernel,
         bq=bq, bkv=bkv, problem=p, traffic=traffic, vmem=vmem,
-        fallback_reason=fallback, tuned=tuned)
+        fallback_reason=fallback, tuned=tuned,
+        bwd=_plan_backward(spec, p, kernel, bq))
 
 
 def attn_plan(spec: AttnSpec, shapes: Tuple[int, ...]) -> AttnPlan:
@@ -812,20 +921,30 @@ def _decode_attention_paged_xla(q, k_pages, v_pages, page_table, pos, *,
 # attn_execute — ONE generic custom VJP for the whole family
 # ---------------------------------------------------------------------------
 
+# the Pallas kernels run per device, each on its rows of the batch
+_ROWS3, _ROWS4 = ("batch", None, None), ("batch", None, None, None)
+
+
+def _flash_forward(pl: AttnPlan, scale, q_offset, q, k, v,
+                   return_lse=False):
+    spec = pl.spec
+    return shd.per_device(
+        lambda q, k, v: flash_attention(
+            q, k, v, causal=spec.causal, window=spec.window,
+            scale=scale, q_offset=q_offset, bq=pl.bq, bkv=pl.bkv,
+            interpret=pl.dispatch == "interpret",
+            vmem_bytes=pl.vmem_bytes, return_lse=return_lse),
+        (q, k, v), (_ROWS4,) * 3,
+        (_ROWS4, _ROWS3) if return_lse else _ROWS4)
+
+
 def _dispatch_attn(pl: AttnPlan, scale, q_offset, q, k, v, pos,
                    page_table):
     spec = pl.spec
     interp = pl.dispatch == "interpret"
     kern = pl.kernel
-    # the Pallas kernels run per device, each on its rows of the batch
-    rows3, rows4 = ("batch", None, None), ("batch", None, None, None)
     if kern == "flash_attention":
-        return shd.per_device(
-            lambda q, k, v: flash_attention(
-                q, k, v, causal=spec.causal, window=spec.window,
-                scale=scale, q_offset=q_offset, bq=pl.bq, bkv=pl.bkv,
-                interpret=interp, vmem_bytes=pl.vmem_bytes),
-            (q, k, v), (rows4,) * 3, rows4)
+        return _flash_forward(pl, scale, q_offset, q, k, v)
     if kern == "attention_blocked":
         return attention_blocked(
             q, k, v, causal=spec.causal, window=spec.window, scale=scale,
@@ -842,7 +961,7 @@ def _dispatch_attn(pl: AttnPlan, scale, q_offset, q, k, v, pos,
             lambda q, k, v, pos: flash_decode(
                 q, k, v, pos, window=spec.window, bkv=pl.bkv, scale=scale,
                 interpret=interp, vmem_bytes=pl.vmem_bytes),
-            (q, k, v, pos), (rows3, rows4, rows4, ("batch",)), rows3)
+            (q, k, v, pos), (_ROWS3, _ROWS4, _ROWS4, ("batch",)), _ROWS3)
     if kern == "xla_decode":
         return _decode_attention_xla(q, k, v, pos, window=spec.window)
     if kern == "flash_decode_paged":
@@ -853,7 +972,7 @@ def _dispatch_attn(pl: AttnPlan, scale, q_offset, q, k, v, pos,
                 q, k, v, tbl, pos, window=spec.window, scale=scale,
                 interpret=interp, vmem_bytes=pl.vmem_bytes),
             (q, k, v, page_table, pos),
-            (rows3, pool, pool, ("batch", None), ("batch",)), rows3)
+            (_ROWS3, pool, pool, ("batch", None), ("batch",)), _ROWS3)
     if kern == "xla_decode_paged":
         return _decode_attention_paged_xla(q, k, v, page_table, pos,
                                            window=spec.window)
@@ -863,27 +982,42 @@ def _dispatch_attn(pl: AttnPlan, scale, q_offset, q, k, v, pos,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
 def _attn_core(pl: AttnPlan, scale, q_offset, q, k, v, pos, page_table):
     """The whole attention family behind one VJP: forward dispatches on
-    the plan's kernel; backward recomputes through the differentiable
-    reference composition (the Pallas flash kernels are forward-only).
+    the plan's kernel, backward on its ``bwd_kernel``.
     ``pos``/``page_table`` are int data operands — float0 cotangents."""
     return _dispatch_attn(pl, scale, q_offset, q, k, v, pos, page_table)
 
 
 def _attn_core_fwd(pl, scale, q_offset, q, k, v, pos, page_table):
+    if pl.bwd_kernel == "flash_attention_bwd":
+        # the only caller of the forward that asks for its lse
+        out, lse = _flash_forward(pl, scale, q_offset, q, k, v,
+                                  return_lse=True)
+        return out, (q, k, v, pos, page_table, out, lse)
     out = _attn_core(pl, scale, q_offset, q, k, v, pos, page_table)
-    return out, (q, k, v, pos, page_table)
+    return out, (q, k, v, pos, page_table, None, None)
 
 
 def _attn_core_bwd(pl, scale, q_offset, res, g):
+    q, k, v, pos, page_table, out, lse = res
+    spec = pl.spec
+    bw = pl.bwd
+    if bw.kernel == "flash_attention_bwd":
+        dq, dk, dv = shd.per_device(
+            lambda q, k, v, out, lse, g: flash_attention_bwd(
+                q, k, v, out, lse, g, causal=spec.causal,
+                window=spec.window, scale=scale, q_offset=q_offset,
+                bq=bw.bq, bkv=bw.bkv,
+                interpret=pl.dispatch == "interpret", vmem_bytes=bw.vmem),
+            (q, k, v, out, lse, g), (_ROWS4,) * 4 + (_ROWS3, _ROWS4),
+            (_ROWS4,) * 3)
+        return dq, dk, dv, None, None
     # Recompute backward: re-run the differentiable composition at the
     # saved inputs and pull the cotangent through it.  Long prefill
     # recomputes through the blocked path (lax.scan + checkpoint — no
     # (sq, skv) score materialization); short prefill through the plain
     # reference; decode through the head-grouped XLA einsums.
-    q, k, v, pos, page_table = res
-    spec = pl.spec
     if spec.mode == "prefill":
-        if max(pl.sq, pl.skv) > BLOCKED_ATTN_THRESHOLD:
+        if bw.kernel == "attention_blocked":
             def fwd(q, k, v):
                 return attention_blocked(
                     q, k, v, causal=spec.causal, window=spec.window,
@@ -920,9 +1054,12 @@ def _execute_event(pl: AttnPlan) -> None:
     if ek in _executed:
         return
     _executed.add(ek)
+    bw = pl.bwd
     telemetry.event("attn.execute", spec=pl.spec.key, shape=pl.shape_key,
                     kernel=pl.kernel, bq=pl.bq, bkv=pl.bkv,
-                    hbm_bytes=pl.hbm_bytes, flops=pl.flops)
+                    hbm_bytes=pl.hbm_bytes, flops=pl.flops,
+                    bwd_kernel=bw.kernel, bwd_bq=bw.bq, bwd_bkv=bw.bkv,
+                    bwd_block_share=bw.block_share)
 
 
 def attn_execute(pl: AttnPlan, q, k, v, *, pos=None, page_table=None,

@@ -339,6 +339,110 @@ def test_decode_grads_with_int_pos_operand():
                                    rtol=1e-4, atol=1e-4)
 
 
+FLASH_GRAD_CASES = {
+    # name: (b, sq, skv, hq, hkv, d, causal, window, q_offset, dtype)
+    "causal_mha_d64_f32": (1, 256, 256, 2, 2, 64, True, 0, None,
+                           jnp.float32),
+    "causal_window_gqa15_5_bf16": (1, 384, 384, 15, 5, 64, True, 100, None,
+                                   jnp.bfloat16),
+    "full_mqa_d128_f32": (1, 256, 256, 4, 1, 128, False, 0, None,
+                          jnp.float32),
+    "causal_sq_lt_skv_gqa_bf16": (1, 128, 384, 6, 2, 64, True, 0, None,
+                                  jnp.bfloat16),
+    "causal_q_offset_f32": (1, 256, 384, 2, 2, 64, True, 0, 64,
+                            jnp.float32),
+    "cross_ragged_gqa_f32": (1, 200, 328, 4, 2, 64, False, 0, None,
+                             jnp.float32),
+    "ragged_window_d128_bf16": (2, 200, 200, 4, 2, 128, True, 64, None,
+                                jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_GRAD_CASES))
+def test_flash_backward_grads_match_ref(monkeypatch, case):
+    """The Pallas backward (128-blocks, so that the causal and window
+    block skips engage) against the reference's VJP, in interpret
+    mode."""
+    monkeypatch.setenv("REPRO_KERNELS", "interpret")
+    b, sq, skv, hq, hkv, d, causal, window, q_offset, dt = \
+        FLASH_GRAD_CASES[case]
+    q, k, v = (_rand((b, sq, hq, d), dt, 0), _rand((b, skv, hkv, d), dt, 1),
+               _rand((b, skv, hkv, d), dt, 2))
+    g = _rand((b, sq, hq, d), dt, 3)
+    calls = []
+    bwd = attn_api.flash_attention_bwd
+    monkeypatch.setattr(attn_api, "flash_attention_bwd",
+                        lambda *a, **kw: calls.append(kw) or bwd(*a, **kw))
+    kw = dict(causal=causal, window=window, q_offset=q_offset)
+    got = jax.vjp(lambda *a: ops.attention(*a, bq=128, bkv=128, **kw),
+                  q, k, v)[1](g)
+    want = jax.vjp(lambda *a: _ref.attention_ref(*a, **kw), q, k, v)[1](g)
+    (pl,) = ops.attn_plans()
+    assert pl.bwd_kernel == "flash_attention_bwd" and len(calls) == 1
+    assert (calls[0]["bq"], calls[0]["bkv"]) == (128, 128)
+    for gt, w in zip(got, want):
+        assert gt.dtype == w.dtype == dt
+        gt, w = np.asarray(gt, np.float32), np.asarray(w, np.float32)
+        if dt == jnp.float32:
+            np.testing.assert_allclose(gt, w, rtol=1e-4, atol=1e-4)
+        else:       # P and dS meet the MXU at bf16, as in the XLA path
+            assert np.linalg.norm(gt - w) < 1e-2 * np.linalg.norm(w)
+
+
+def test_flash_plan_records_its_backward(monkeypatch):
+    from repro import telemetry
+    monkeypatch.setenv("REPRO_KERNELS", "interpret")
+    pl = ops.attn_plan(ops.AttnSpec(group=3), (16, 2048, 2048, 15, 5, 64))
+    bw = pl.bwd
+    assert bw.kernel == pl.bwd_kernel == "flash_attention_bwd"
+    assert (bw.bq, bw.bkv) == (512, 512)
+    assert bw.blocks == (10, 16) and bw.block_share == 10 / 16
+    assert len(bw.vmem) == 2 and all(fits_vmem_bytes(v) for v in bw.vmem)
+    assert "flash_attention_bwd bq=512 bkv=512, 10 of 16 blocks" \
+        in pl.explain()
+    # a window empties whole blocks below the diagonal too
+    w = ops.attn_plan(ops.AttnSpec(window=512), (1, 2048, 2048, 2, 2, 64))
+    assert w.bwd.blocks == (7, 16)
+    rec = telemetry.enable(telemetry.Recorder())
+    try:
+        q, k, v = _qkv(sq=256, skv=256, hq=4, hkv=2)
+        ops.attention(q, k, v)
+    finally:
+        telemetry.disable()
+    (ev,) = [e["attrs"] for e in rec.events if e["name"] == "attn.execute"]
+    assert (ev["bwd_kernel"], ev["bwd_bq"], ev["bwd_bkv"],
+            ev["bwd_block_share"]) == ("flash_attention_bwd", 256, 256, 1.0)
+
+
+@pytest.mark.parametrize("mode,shapes,spec,want", [
+    ("ref", (1, 2048, 2048, 2, 2, 64), {}, "attention_blocked"),
+    ("ref", (1, 256, 256, 2, 2, 64), {}, "xla_ref"),
+    ("interpret", (1, 64, 128, 2, 2, 64), {}, "xla_ref"),
+    ("interpret", (1, 256, 256, 2, 2, 64), {"bq": 64}, "xla_ref"),
+    ("interpret", (2, 256, 4, 2, 64), {"mode": "decode", "group": 2},
+     "xla_decode"),
+])
+def test_other_plans_keep_the_recompute_backward(monkeypatch, mode, shapes,
+                                                  spec, want):
+    monkeypatch.setenv("REPRO_KERNELS", mode)
+    pl = ops.attn_plan(ops.AttnSpec(**spec), shapes)
+    assert pl.bwd_kernel == want and pl.bwd.bq is None
+    assert f"backward : {want} (recompute)" in pl.explain()
+
+
+def test_primal_forward_does_not_ask_for_the_lse(monkeypatch):
+    monkeypatch.setenv("REPRO_KERNELS", "interpret")
+    seen = []
+    fwd = attn_api.flash_attention
+    monkeypatch.setattr(
+        attn_api, "flash_attention",
+        lambda *a, **kw: seen.append(kw["return_lse"]) or fwd(*a, **kw))
+    q, k, v = _qkv(hq=4, hkv=2)
+    ops.attention(q, k, v)
+    jax.grad(lambda q: ops.attention(q, k, v).astype(jnp.float32).sum())(q)
+    assert seen == [False, True]
+
+
 def test_exactly_one_custom_vjp_in_attn_api():
     import inspect
     src = inspect.getsource(attn_api)

@@ -182,7 +182,9 @@ def f(a, b, u, r, q, kv):
     z = ops.gemm(a, b, b2=u, activation="silu")
     o = ops.attention(q, kv, kv, causal=True)
     g = jax.grad(lambda a: jnp.sum(ops.gemm(a, b) ** 2))(a)
-    return y, z, o, g
+    dq, dkv = jax.grad(lambda q, kv: jnp.sum(
+        ops.attention(q, kv, kv, causal=True) ** 2), argnums=(0, 1))(q, kv)
+    return y, z, o, g, dq, dkv
 
 args = (a, b, u, r, q, kv)
 want = jax.jit(f)(*args)
@@ -196,7 +198,7 @@ assert text.count("sdy.manual_computation(") >= 4, text.count(
     "sdy.manual_computation(")
 # GEMM blocks are (rows / data, cols / model); attention splits batch
 assert "tensor<32x96xf32>" in text and "tensor<1x128x4x32xf32>" in text
-for w, g in zip(want, got):
+for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
     np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                rtol=1e-5, atol=1e-4)
 print("PER-DEVICE-OK")
@@ -207,7 +209,8 @@ def test_pallas_kernels_run_per_device_under_a_mesh():
     """A Pallas call under a multi-device mesh runs per device through
     shard_map (GSPMD cannot partition a Mosaic kernel) and gives the
     unsharded result: forward GEMM with epilogue, gated GEMM, flash
-    attention and a GEMM backward, interpret mode on 4 CPU devices."""
+    attention, a GEMM backward and the flash attention backward,
+    interpret mode on 4 CPU devices."""
     import os
     import subprocess
     import sys

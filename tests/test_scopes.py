@@ -3,7 +3,9 @@ the benchmark sums device time by (``benchmarks/chip/regions.py``) is
 named in some instruction's ``op_name``, every matrix product and every
 op of the attention backward falls in a region, only bookkeeping is
 left unscoped, and the scopes change nothing but metadata.  The serving
-paths name their K/V pool update and their sampling."""
+paths name their K/V pool update and their sampling.  Under Pallas
+dispatch the attention backward is the flash backward kernels, inside
+the ``attention`` region, and no blocked XLA recompute is left."""
 
 import contextlib
 import importlib.util
@@ -36,12 +38,12 @@ def _regions():
 R = _regions()
 
 
-def _compiled_step_text() -> str:
+def _compiled_step_text(shape=(2, 64)) -> str:
     cfg = get_smoke_config("smollm-360m")
     step = TS.make_train_step(cfg)
     state = jax.eval_shape(lambda: TS.init_state(jax.random.PRNGKey(0),
                                                  cfg))
-    batch = {k: jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    batch = {k: jax.ShapeDtypeStruct(shape, jnp.int32)
              for k in ("tokens", "labels")}
     return jax.jit(step, donate_argnums=(0,)).lower(state, batch) \
         .compile().as_text()
@@ -115,6 +117,27 @@ def test_only_bookkeeping_is_unscoped(text, hlo, table):
     assert left == []
     assert sum(table[n] == R.UNSCOPED for n in R.executed(hlo)) \
         < 0.05 * len(R.executed(hlo))
+
+
+@pytest.fixture(scope="module")
+def interpret_hlo():
+    """The step under Pallas (interpret) dispatch, at a length past the
+    blocked path's threshold (1024): the XLA backward that the flash
+    backward replaces was a blocked scan there."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("REPRO_KERNELS", "interpret")
+        return R.parse(_compiled_step_text((1, 1152)))
+
+
+def test_flash_backward_replaces_the_blocked_recompute(interpret_hlo):
+    hlo = interpret_hlo
+    assert [n for n, ins in hlo.instrs.items()
+            if "attention_blocked" in (ins.op_name or "")] == []
+    table = R.regions(hlo)
+    bwd = [n for n in R.executed(hlo)
+           if "jit(flash_attention_bwd)" in (hlo.instrs[n].op_name or "")]
+    assert bwd
+    assert {n: table[n] for n in bwd if table[n] != "attention"} == {}
 
 
 def _strip(text: str) -> str:

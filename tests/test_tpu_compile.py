@@ -12,6 +12,8 @@ llama-class 4096x14336 MLP.
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -129,3 +131,30 @@ def test_kernel_compiles_for_v5e(case, one_chip, pallas):
         assert p.fallback_reason is None, p.explain()
     for p in ops.attn_plans():
         assert not p.kernel.startswith("xla"), p.explain()
+
+
+def test_flash_backward_compiles_for_v5e(one_chip, pallas):
+    """The training path of the smollm-360m cell's attention: the
+    forward that writes its lse and the two backward kernels, at seq
+    2048, 15/5 heads of 64 and the cell's batch of 16 on one chip, each
+    under the scoped VMEM limit of its modeled working set."""
+    from repro.core.memory_model import vmem_limit_bytes
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return ops.attention(q, k, v, causal=True).astype(F32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds((16, 2048, 15, 64)), sds((16, 2048, 5, 64)),
+        sds((16, 2048, 5, 64))).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3
+    assert sum("flash_attention_bwd" in ln for ln in calls) == 2
+    (pl,) = ops.attn_plans()
+    assert pl.bwd_kernel == "flash_attention_bwd", pl.explain()
+    scoped = {int(m) for ln in calls for m in re.findall(
+        r'"scoped_memory_configs":\[\{[^]]*"size":"(\d+)"', ln)}
+    assert scoped == {vmem_limit_bytes(v)
+                      for v in (pl.vmem_bytes, *pl.bwd.vmem)}
