@@ -165,7 +165,9 @@ def register(cfg: ModelConfig) -> ModelConfig:
 
 def get_config(name: str) -> ModelConfig:
     if name not in _REGISTRY:
-        mod = name.replace("-", "_").replace(".", "_")
+        # a variant (``<arch>-12l``) lives in its architecture's module
+        arch = next((a for a in ARCH_IDS if name.startswith(a)), name)
+        mod = arch.replace("-", "_").replace(".", "_")
         importlib.import_module(f"repro.configs.{mod}")
     return _REGISTRY[name]
 

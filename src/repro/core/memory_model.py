@@ -75,8 +75,10 @@ def vmem_footprint(tile: TileConfig, p: GemmProblem,
     * ``aie`` (output-stationary): A and B blocks stream (x pipeline
       stages); the fp32/int32 accumulator is a persistent scratch; the out
       block streams.
-    * ``tb`` (A-stationary): the A block is resident (single copy); B and
-      the read-modify-written C stream (x pipeline stages each way).
+    * ``tb`` (A-stationary): the A block stays for a whole n sweep, but
+      Pallas double-buffers it like every input block, so it is billed
+      x pipeline stages too; B and the read-modify-written C stream (x
+      pipeline stages each way).
 
     A and B are billed at *their own* dtype widths — an int8 B block costs
     one byte/element, which is exactly what lets the DSE roughly double
@@ -136,10 +138,11 @@ def vmem_footprint(tile: TileConfig, p: GemmProblem,
             residual_bytes=residual,
             temp_bytes=temp,
         )
-    # 'tb': A resident; C is both input and output stream (read-modify-
-    # write accumulation in the output buffer, like the paper's PL adders).
+    # 'tb': A resident across the n sweep, in Pallas's two buffers; C is
+    # both input and output stream (read-modify-write accumulation in the
+    # output buffer, like the paper's PL adders).
     return VmemFootprint(
-        a_bytes=a,
+        a_bytes=PIPELINE_STAGES * a,
         b_bytes=PIPELINE_STAGES * b,
         out_bytes=2 * PIPELINE_STAGES * padded_tile_bytes(
             tile.bm, tile.bn, p.acc_dtype, chip),

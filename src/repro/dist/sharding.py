@@ -31,11 +31,14 @@ GSPMD partitioning the layout engine's shardings are written for.
 from __future__ import annotations
 
 import contextlib
+import math
 import os
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
+
+from repro import telemetry
 
 # ---------------------------------------------------------------------------
 # Mesh lifecycle
@@ -168,7 +171,30 @@ def act(x: jax.Array, *axes) -> jax.Array:
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
-def per_device(fn: Callable, args: Sequence, axes: Sequence, out_axes):
+def _spec_axes(entry) -> Tuple[str, ...]:
+    return entry if isinstance(entry, tuple) else (entry,) if entry \
+        else ()
+
+
+def _per_device_event(name: str, args, specs, sizes) -> None:
+    """The ``dist.per_device`` telemetry event of one call: each
+    argument's global shape, spec, bytes per device and the dims left
+    whole on every device."""
+    rows = []
+    for x, spec in zip(args, specs):
+        local = [int(d) // math.prod(sizes[a] for a in _spec_axes(e))
+                 for d, e in zip(x.shape, spec)]
+        rows.append({
+            "shape": list(x.shape), "spec": [list(_spec_axes(e))
+                                             for e in spec],
+            "bytes_per_device": math.prod(local) * x.dtype.itemsize,
+            "whole_dims": [i for i, e in enumerate(spec) if not e]})
+    telemetry.event("dist.per_device", fn=name, mesh=dict(sizes),
+                    args=rows)
+
+
+def per_device(fn: Callable, args: Sequence, axes: Sequence, out_axes, *,
+               name: str):
     """``fn(*args)`` run as one program per device of the active mesh.
 
     GSPMD cannot partition a Mosaic (Pallas TPU) kernel, so under a
@@ -180,6 +206,8 @@ def per_device(fn: Callable, args: Sequence, axes: Sequence, out_axes):
     every device, so the logical axes may only split dims that ``fn``
     treats independently.  Outside a mesh, on one
     device, or already inside a ``shard_map``, ``fn`` runs as is.
+    With telemetry on, a call through ``shard_map`` records a
+    ``dist.per_device`` event named ``name`` when it is traced.
     """
     mesh = current_mesh()
     if (not isinstance(mesh, Mesh) or mesh_devices(mesh) <= 1
@@ -187,6 +215,10 @@ def per_device(fn: Callable, args: Sequence, axes: Sequence, out_axes):
         return fn(*args)
     sizes = axis_sizes(mesh)
     live = [i for i, x in enumerate(args) if x is not None]
+    in_specs = tuple(logical_spec(args[i].shape, axes[i], sizes)
+                     for i in live)
+    if telemetry.enabled():
+        _per_device_event(name, [args[i] for i in live], in_specs, sizes)
 
     def local(*shards):
         full = list(args)
@@ -196,9 +228,7 @@ def per_device(fn: Callable, args: Sequence, axes: Sequence, out_axes):
 
     out = jax.eval_shape(fn, *args)
     return jax.shard_map(
-        local, mesh=mesh,
-        in_specs=tuple(logical_spec(args[i].shape, axes[i], sizes)
-                       for i in live),
+        local, mesh=mesh, in_specs=in_specs,
         out_specs=(tuple(logical_spec(o.shape, a, sizes)
                          for o, a in zip(out, out_axes))
                    if isinstance(out, tuple)

@@ -736,7 +736,7 @@ def _dispatch_grouped(pl: GemmPlan, a, b, b_scale, group_sizes, bias
         bank = (None, None, "model")
         return shd.per_device(local, (a, b, b_scale, sizes, bias3),
                               ((None, None), bank, bank, (None,), bank),
-                              (None, "model"))
+                              (None, "model"), name="gemm_grouped")
     return _ref.gemm_grouped_ref(a, b, sizes, b_scale=b_scale,
                                  bias=bias3, activation=act,
                                  out_dtype=out_dtype)
@@ -752,22 +752,34 @@ def _dispatch(pl: GemmPlan, a, b, b_scale, b2, b2_scale, bias, residual,
     out_dtype = jnp.dtype(pl.problem.out_dtype)
     if use_pallas():
         # per device: rows split over the batch axes, columns over
-        # 'model'; the contraction stays whole
+        # 'model'; the contraction stays whole.  Each device runs the
+        # plan of its own block of the product.
         rows, cols, out = ("batch", None), (None, "model"), \
             ("batch", "model")
+
+        def block_plan(a, b):
+            shape = (a.shape[0], a.shape[1], b.shape[1])
+            return pl if shape == (pl.m, pl.k, pl.n) else plan(spec, shape)
+
+        def gated(a, bg, bu, sg, su):
+            lp = block_plan(a, bg)
+            return _gated_pallas(a, bg, bu, lp.tile, out_dtype, act,
+                                 lp.vmem_bytes, sg=sg, su=su)
+
+        def dense(a, b, s, bias, res, osc):
+            lp = block_plan(a, b)
+            return _gemm_pallas(a, b, lp.tile, out_dtype, lp.vmem_bytes,
+                                b_scale=s, bias=bias, residual=res,
+                                out_scale=osc, activation=act)
+
         if spec.gated:
-            return shd.per_device(
-                lambda a, bg, bu, sg, su: _gated_pallas(
-                    a, bg, bu, pl.tile, out_dtype, act, pl.vmem_bytes,
-                    sg=sg, su=su),
-                (a, b, b2, b_scale, b2_scale),
-                (rows, cols, cols, cols, cols), out)
-        return shd.per_device(
-            lambda a, b, s, bias, res, osc: _gemm_pallas(
-                a, b, pl.tile, out_dtype, pl.vmem_bytes, b_scale=s,
-                bias=bias, residual=res, out_scale=osc, activation=act),
-            (a, b, b_scale, bias, residual, out_scale),
-            (rows, cols, cols, cols, out, (None, None)), out)
+            return shd.per_device(gated, (a, b, b2, b_scale, b2_scale),
+                                  (rows, cols, cols, cols, cols), out,
+                                  name="gemm_gated")
+        return shd.per_device(dense, (a, b, b_scale, bias, residual,
+                                      out_scale),
+                              (rows, cols, cols, cols, out, (None, None)),
+                              out, name="gemm")
     if spec.gated:
         return _ref.gemm_gated_ref(a, b, b2, activation=act,
                                    bg_scale=b_scale, bu_scale=b2_scale,

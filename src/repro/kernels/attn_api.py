@@ -925,17 +925,29 @@ def _decode_attention_paged_xla(q, k_pages, v_pages, page_table, pos, *,
 _ROWS3, _ROWS4 = ("batch", None, None), ("batch", None, None, None)
 
 
+def _flash_axes(q, k):
+    """Per-device axes of the flash kernels' (b, s, h, d) operands and
+    (b, h, s) lse rows: rows over the batch axes, and heads over
+    'model' where it divides both the query and the kv heads, so that
+    each device holds whole GQA groups."""
+    m = shd.axis_sizes(shd.current_mesh()).get("model", 1)
+    heads = "model" if q.shape[2] % m == 0 and k.shape[2] % m == 0 \
+        else None
+    return ("batch", None, heads, None), ("batch", heads, None)
+
+
 def _flash_forward(pl: AttnPlan, scale, q_offset, q, k, v,
                    return_lse=False):
     spec = pl.spec
+    qkv, lse = _flash_axes(q, k)
     return shd.per_device(
         lambda q, k, v: flash_attention(
             q, k, v, causal=spec.causal, window=spec.window,
             scale=scale, q_offset=q_offset, bq=pl.bq, bkv=pl.bkv,
             interpret=pl.dispatch == "interpret",
             vmem_bytes=pl.vmem_bytes, return_lse=return_lse),
-        (q, k, v), (_ROWS4,) * 3,
-        (_ROWS4, _ROWS3) if return_lse else _ROWS4)
+        (q, k, v), (qkv,) * 3, (qkv, lse) if return_lse else qkv,
+        name="flash_attention")
 
 
 def _dispatch_attn(pl: AttnPlan, scale, q_offset, q, k, v, pos,
@@ -961,7 +973,8 @@ def _dispatch_attn(pl: AttnPlan, scale, q_offset, q, k, v, pos,
             lambda q, k, v, pos: flash_decode(
                 q, k, v, pos, window=spec.window, bkv=pl.bkv, scale=scale,
                 interpret=interp, vmem_bytes=pl.vmem_bytes),
-            (q, k, v, pos), (_ROWS3, _ROWS4, _ROWS4, ("batch",)), _ROWS3)
+            (q, k, v, pos), (_ROWS3, _ROWS4, _ROWS4, ("batch",)), _ROWS3,
+            name="flash_decode")
     if kern == "xla_decode":
         return _decode_attention_xla(q, k, v, pos, window=spec.window)
     if kern == "flash_decode_paged":
@@ -972,7 +985,8 @@ def _dispatch_attn(pl: AttnPlan, scale, q_offset, q, k, v, pos,
                 q, k, v, tbl, pos, window=spec.window, scale=scale,
                 interpret=interp, vmem_bytes=pl.vmem_bytes),
             (q, k, v, page_table, pos),
-            (_ROWS3, pool, pool, ("batch", None), ("batch",)), _ROWS3)
+            (_ROWS3, pool, pool, ("batch", None), ("batch",)), _ROWS3,
+            name="flash_decode_paged")
     if kern == "xla_decode_paged":
         return _decode_attention_paged_xla(q, k, v, page_table, pos,
                                            window=spec.window)
@@ -1002,14 +1016,15 @@ def _attn_core_bwd(pl, scale, q_offset, res, g):
     spec = pl.spec
     bw = pl.bwd
     if bw.kernel == "flash_attention_bwd":
+        qkv, rows = _flash_axes(q, k)
         dq, dk, dv = shd.per_device(
             lambda q, k, v, out, lse, g: flash_attention_bwd(
                 q, k, v, out, lse, g, causal=spec.causal,
                 window=spec.window, scale=scale, q_offset=q_offset,
                 bq=bw.bq, bkv=bw.bkv,
                 interpret=pl.dispatch == "interpret", vmem_bytes=bw.vmem),
-            (q, k, v, out, lse, g), (_ROWS4,) * 4 + (_ROWS3, _ROWS4),
-            (_ROWS4,) * 3)
+            (q, k, v, out, lse, g), (qkv,) * 4 + (rows, qkv), (qkv,) * 3,
+            name="flash_attention_bwd")
         return dq, dk, dv, None, None
     # Recompute backward: re-run the differentiable composition at the
     # saved inputs and pull the cotangent through it.  Long prefill

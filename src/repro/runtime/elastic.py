@@ -15,9 +15,10 @@ from typing import Optional
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import telemetry
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs.base import ModelConfig
-from repro.dist import layout
+from repro.dist import layout, sharding
 from repro.optim import adafactor, adamw
 
 
@@ -41,6 +42,21 @@ def state_specs(target_state, cfg: ModelConfig, mesh: jax.sharding.Mesh,
 
 def state_shardings(target_state, cfg: ModelConfig,
                     mesh: jax.sharding.Mesh, layout_name=None):
+    """NamedShardings for a TrainState on ``mesh``.  With telemetry on,
+    the layout resolved here is recorded as a ``dist.layout`` event:
+    the strategy, the mesh's axis sizes and every strategy's scored
+    bytes per device and collective bytes."""
+    if layout_name is None:
+        sizes = sharding.axis_sizes(mesh)
+        layout_name = layout.choose_layout(cfg, sizes)
+        if telemetry.enabled():
+            scored = layout.score_layouts(cfg, sizes)
+            telemetry.event("dist.layout", strategy=layout_name,
+                            mesh=sizes, scores={
+                                s: {k: v[k] for k in (
+                                    "mem_bytes_per_device",
+                                    "collective_bytes_per_device")}
+                                for s, v in scored.items()})
     specs = state_specs(target_state, cfg, mesh, layout_name)
     return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
                         is_leaf=lambda x: isinstance(x, P))

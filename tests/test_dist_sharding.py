@@ -197,7 +197,9 @@ with shd.use_mesh(mesh):
 assert text.count("sdy.manual_computation(") >= 4, text.count(
     "sdy.manual_computation(")
 # GEMM blocks are (rows / data, cols / model); attention splits batch
-assert "tensor<32x96xf32>" in text and "tensor<1x128x4x32xf32>" in text
+# over data and heads over model (4 query and 2 kv heads per call)
+assert "tensor<32x96xf32>" in text and "tensor<1x128x2x32xf32>" in text
+assert "tensor<1x128x1x32xf32>" in text
 for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
     np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                rtol=1e-5, atol=1e-4)
@@ -236,6 +238,114 @@ def test_per_device_runs_as_is_without_a_mesh():
 
     x = jnp.ones((4, 8))
     out = shd.per_device(fn, (x, None), (("batch", None), None),
-                         ("batch", None))
+                         ("batch", None), name="double")
     assert calls == [((4, 8), None)]
     assert float(out[0, 0]) == 2.0
+
+
+_EVENTS_SCRIPT = r"""
+import json, re
+import jax, jax.numpy as jnp
+from repro import telemetry
+from repro.configs.base import get_smoke_config
+from repro.dist import sharding as shd
+from repro.launch import train as TR
+from repro.launch.mesh import make_host_mesh
+
+cfg = get_smoke_config("h2o-danube-3-4b")
+mesh = make_host_mesh(data=2, model=2)
+batch = {k: jax.ShapeDtypeStruct((4, 128), jnp.int32)
+         for k in ("tokens", "labels")}
+
+
+def step_hlo():
+    # the compiled step without metadata and the source-location tables
+    # it points into, which differ from one trace to the next
+    state, step, _ = TR.build(cfg, mesh)
+    with shd.use_mesh(mesh):
+        text = step.lower(state, batch).compile().as_text()
+    return [re.sub(r",? metadata=\{[^}]*\}", "", ln)
+            for ln in text.splitlines()
+            if ln.startswith(("HloModule", " ", "ENTRY", "%", "}"))]
+
+
+off = step_hlo()
+rec = telemetry.enable()
+on = step_hlo()
+telemetry.disable()
+events = [e for e in rec.events if e["name"].startswith("dist.")]
+print("EVENTS " + json.dumps({"same_hlo": on == off, "events": events}))
+"""
+
+
+@pytest.fixture(scope="module")
+def dist_events():
+    """The ``dist.*`` telemetry events of building and compiling the
+    danube smoke train step on a 2x2 mesh of 4 host devices (interpret
+    mode), and whether the compiled step is the same with telemetry on
+    and off."""
+    import json
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = "src"
+    env["REPRO_KERNELS"] = "interpret"
+    r = subprocess.run([sys.executable, "-c", _EVENTS_SCRIPT],
+                       capture_output=True, text=True, env=env,
+                       cwd=os.path.dirname(os.path.dirname(
+                           os.path.abspath(__file__))),
+                       timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    line = next(x for x in r.stdout.splitlines()
+                if x.startswith("EVENTS "))
+    return json.loads(line[len("EVENTS "):])
+
+
+def test_dist_events_leave_the_compiled_step_alone(dist_events):
+    assert dist_events["same_hlo"]
+
+
+def test_layout_event_records_the_scored_strategies(dist_events):
+    (ev,) = [e["attrs"] for e in dist_events["events"]
+             if e["name"] == "dist.layout"]
+    cfg = get_config("h2o-danube-3-4b-smoke")
+    sizes = {"data": 2, "model": 2}
+    assert ev["strategy"] == layout.choose_layout(cfg, sizes)
+    assert ev["mesh"] == sizes
+    scored = layout.score_layouts(cfg, sizes)
+    assert set(ev["scores"]) == set(layout.STRATEGIES)
+    for s, v in ev["scores"].items():
+        assert v == {k: scored[s][k] for k in (
+            "mem_bytes_per_device", "collective_bytes_per_device")}
+
+
+def test_per_device_events_record_each_argument(dist_events):
+    evs = [e["attrs"] for e in dist_events["events"]
+           if e["name"] == "dist.per_device"]
+    fns = {e["fn"] for e in evs}
+    assert {"gemm", "gemm_gated", "flash_attention",
+            "flash_attention_bwd"} <= fns
+    for e in evs:
+        assert e["mesh"] == {"data": 2, "model": 2}
+        for arg in e["args"]:
+            assert len(arg["spec"]) == len(arg["shape"])
+            split = 1
+            for axes in arg["spec"]:
+                for a in axes:
+                    split *= e["mesh"][a]
+            assert arg["whole_dims"] == [i for i, axes in
+                                         enumerate(arg["spec"]) if not axes]
+            # float32 smoke model, int32 or float32 operands: 4 bytes
+            n = 1
+            for d in arg["shape"]:
+                n *= d
+            assert arg["bytes_per_device"] * split == 4 * n
+    # the forward flash call: q, k and v split over the batch rows and
+    # the heads (4 query and 2 kv heads over 'model'), whole in s and d
+    fwd = next(e for e in evs if e["fn"] == "flash_attention")
+    q, k, _ = fwd["args"]
+    assert q["spec"] == [["data"], [], ["model"], []]
+    assert q["shape"][2] == 4 and k["shape"][2] == 2
+    assert q["whole_dims"] == [1, 3]

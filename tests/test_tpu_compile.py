@@ -7,7 +7,11 @@ whose VMEM use exceeds its scoped limit, which the CPU suite and the
 Pallas interpreter cannot see.  Widths are those of the shipped
 configs: smollm-360m (d_model 960, d_ff 2560, vocab 49152, 15/5 heads
 of 64), qwen3-moe expert banks (d_model 4096, d_ff 1536) and a
-llama-class 4096x14336 MLP.
+llama-class 4096x14336 MLP.  The h2o-danube-3-4b cases are the blocks
+one chip of its (data 2, model 2) training mesh runs at seq 4096 and
+global batch 8, forward and backward: 4 rows of the batch, 16 query and
+4 kv heads of 120, and each GEMM's rows split over 'data' and columns
+over 'model' with the contraction whole.
 """
 
 from __future__ import annotations
@@ -158,3 +162,96 @@ def test_flash_backward_compiles_for_v5e(one_chip, pallas):
         r'"scoped_memory_configs":\[\{[^]]*"size":"(\d+)"', ln)}
     assert scoped == {vmem_limit_bytes(v)
                       for v in (pl.vmem_bytes, *pl.bwd.vmem)}
+
+
+#: (m, k, n) of the GEMMs one chip of the danube mesh runs: q and o
+#: projections, k/v, the gated MLP, its down projection, and one chunk
+#: of the lm head (seq 4096 / 8 chunks x 4 rows)
+DANUBE_GEMMS = {
+    "wq_16384x3840x1920": (16384, 3840, 1920, BF16),
+    "wkv_16384x3840x480": (16384, 3840, 480, BF16),
+    "w_down_16384x10240x1920": (16384, 10240, 1920, BF16),
+    "lm_head_2048x3840x16000_f32": (2048, 3840, 16000, F32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DANUBE_GEMMS))
+def test_danube_shard_gemm_compiles_for_v5e(case, one_chip, pallas):
+    """Forward and backward of one GEMM of a danube model shard."""
+    m, k, n, out = DANUBE_GEMMS[case]
+
+    def loss(a, b):
+        return ops.gemm(a, b, out_dtype=out).astype(F32).sum()
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        sds((m, k)), sds((k, n))).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    for p in ops.plans():
+        assert p.fallback_reason is None, p.explain()
+
+
+def test_danube_gated_mlp_compiles_for_v5e(one_chip, pallas):
+    """The SwiGLU of a danube model shard, 3840 -> 5120, forward and
+    backward."""
+    def loss(x, g, u):
+        return ops.gemm(x, g, b2=u, activation="silu").astype(F32).sum()
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds((4, 4096, 3840)), sds((3840, 5120)),
+        sds((3840, 5120))).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    for p in ops.plans():
+        assert p.fallback_reason is None, p.explain()
+
+
+def test_danube_flash_compiles_for_v5e(one_chip, pallas):
+    """The flash forward with its lse and the two backward kernels at
+    head_dim 120 (padded to 128 lanes), seq 4096, 16/4 heads (a GQA
+    group of 4), 4 rows: one chip's share of the danube cell."""
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return ops.attention(q, k, v, causal=True,
+                             window=4096).astype(F32).sum()
+
+    hlo = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        sds((4, 4096, 16, 120)), sds((4, 4096, 4, 120)),
+        sds((4, 4096, 4, 120))).compile().as_text()
+    calls = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    assert len(calls) == 3
+    assert sum("flash_attention_bwd" in ln for ln in calls) == 2
+    (pl,) = ops.attn_plans()
+    assert (pl.hq, pl.hkv, pl.d, pl.spec.group) == (16, 4, 120, 4)
+    assert pl.bwd_kernel == "flash_attention_bwd", pl.explain()
+    assert pl.fallback_reason is None, pl.explain()
+
+
+def test_danube_gemm_under_the_mesh_compiles_for_v5e(topo, one_chip,
+                                                     pallas):
+    """The danube down projection at global shapes under a described
+    (data 2, model 2) mesh, forward and backward: each chip plans its own
+    block of the product, so the tile and its scoped VMEM limit fit the
+    block it runs."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.dist import sharding as shd
+    mesh = shd.make_mesh((2, 2), ("data", "model"), devices=topo.devices)
+
+    def loss(a, b):
+        return ops.gemm(a, b).astype(F32).sum()
+
+    a = jax.ShapeDtypeStruct((32768, 10240), BF16,
+                             sharding=NamedSharding(mesh, P("data", None)))
+    b = jax.ShapeDtypeStruct((10240, 3840), BF16,
+                             sharding=NamedSharding(mesh, P(None, "model")))
+    with shd.use_mesh(mesh):
+        hlo = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(a, b) \
+            .compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert (16384, 10240, 1920) in {(p.m, p.k, p.n) for p in ops.plans()}
