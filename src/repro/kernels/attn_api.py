@@ -429,8 +429,10 @@ def _recompute_family(spec: AttnSpec, p: AttnProblem) -> str:
 
 
 def _computed_blocks(p: AttnProblem, bq: int, bkv: int) -> Tuple[int, int]:
-    """(q, kv) blocks the backward computes, of all: those the causal
-    diagonal and the window leave a key in (the kernels' own ranges)."""
+    """(q, kv) blocks a flash kernel computes, of all: those the causal
+    diagonal and the window leave a key in (the kernels' own ranges), at
+    the kernels' clamp of the blocks."""
+    bq, bkv = min(bq, _pow2_cap(p.sq, 8)), min(bkv, _pow2_cap(p.skv, 128))
     nq, nkv = cdiv(p.sq, bq), cdiv(p.skv, bkv)
     live = 0
     for qi in range(nq):
@@ -584,8 +586,13 @@ class AttnPlan:
     traffic: bandwidth.TrafficEstimate
     vmem: AttnVmemFootprint
     bwd: AttnBackward
+    blocks: Tuple[int, int] = (0, 0)          # forward (computed, of all)
     fallback_reason: Optional[str] = None
     tuned: Optional[TunedInfo] = None
+
+    @property
+    def block_share(self) -> Optional[float]:
+        return self.blocks[0] / self.blocks[1] if self.blocks[1] else None
 
     @property
     def bwd_kernel(self) -> str:
@@ -678,6 +685,10 @@ class AttnPlan:
                 f" ±{tu.spread:.2f}, {src})")
         else:
             lines.append("  source   : analytic")
+        if self.blocks[1]:
+            lines.append(
+                f"  forward  : {self.blocks[0]} of {self.blocks[1]} blocks "
+                f"computed ({100 * self.block_share:.1f}%)")
         bw = self.bwd
         if bw.bq is not None:
             lines.append(
@@ -737,6 +748,7 @@ def _plan_event(pl: AttnPlan, cache: str) -> None:
         hbm_bytes=pl.hbm_bytes, vmem_bytes=pl.vmem_bytes,
         flops=pl.flops, t_model_us=t_model_us,
         bound=pl.traffic.bound, source=pl.source,
+        block_share=pl.block_share, bwd_block_share=pl.bwd.block_share,
         t_measured_us=tuned.t_measured_us if tuned else None,
         measured_vs_model=(tuned.t_measured_us / t_model_us
                            if tuned and t_model_us else None),
@@ -846,7 +858,9 @@ def _resolve(spec: AttnSpec, shapes: Tuple[int, ...]) -> AttnPlan:
         max_pages=f["max_pages"], dispatch=dispatch, kernel=kernel,
         bq=bq, bkv=bkv, problem=p, traffic=traffic, vmem=vmem,
         fallback_reason=fallback, tuned=tuned,
-        bwd=_plan_backward(spec, p, kernel, bq))
+        bwd=_plan_backward(spec, p, kernel, bq),
+        blocks=(_computed_blocks(p, bq, bkv) if kernel == "flash_attention"
+                else (0, 0)))
 
 
 def attn_plan(spec: AttnSpec, shapes: Tuple[int, ...]) -> AttnPlan:
@@ -1074,6 +1088,7 @@ def _execute_event(pl: AttnPlan) -> None:
                     kernel=pl.kernel, bq=pl.bq, bkv=pl.bkv,
                     hbm_bytes=pl.hbm_bytes, flops=pl.flops,
                     bwd_kernel=bw.kernel, bwd_bq=bw.bq, bwd_bkv=bw.bkv,
+                    block_share=pl.block_share,
                     bwd_block_share=bw.block_share)
 
 

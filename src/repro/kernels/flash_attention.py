@@ -8,16 +8,17 @@ kernels.
 
 Features: causal masking, sliding-window (SWA) masking, GQA via
 index-mapped kv heads (no materialized head repeat), fp32 online softmax
-with the standard post-exp re-mask so fully-masked rows stay exact.
+with the standard post-exp re-mask so fully-masked rows stay exact.  The
+forward computes only the kv blocks the mask leaves a key in: a block
+above the causal diagonal or before the window is skipped, and its index
+map clamped to a live block, so no DMA is issued for it.
 
 The backward (:func:`flash_attention_bwd`) is the FlashAttention-2 pair
 of kernels fed by the forward's per-row log-sum-exp
 (``flash_attention(..., return_lse=True)``): a dK/dV kernel that walks
 the q blocks of every q head of a GQA group for one kv block, and a dQ
 kernel that walks the kv blocks for one q block.  Both skip the blocks
-the mask empties (above the causal diagonal, outside the window) and
-clamp those blocks' index maps to a live block, so no DMA is issued for
-them.
+the mask empties as the forward does.
 """
 
 from __future__ import annotations
@@ -47,9 +48,55 @@ def _mask(q_pos, k_pos, *, kv_len, causal, window):
     return mask
 
 
+def _min(a, b):
+    both_ints = isinstance(a, int) and isinstance(b, int)
+    return min(a, b) if both_ints else jnp.minimum(a, b)
+
+
+def _max(a, b):
+    both_ints = isinstance(a, int) and isinstance(b, int)
+    return max(a, b) if both_ints else jnp.maximum(a, b)
+
+
+def kv_block_range(qi, *, bq: int, bkv: int, nkv: int, causal: bool,
+                   window: int, q_offset: int):
+    """[lo, hi] kv blocks that q block ``qi`` sees a key of: the causal
+    diagonal bounds ``hi``, the window ``lo``.  Works on ints and traced
+    scalars alike; an empty range has hi < lo."""
+    lo, hi = 0, nkv - 1
+    if causal:
+        hi = _min(hi, (qi * bq + bq - 1 + q_offset) // bkv)
+    if window > 0:
+        lo = _max(lo, (qi * bq + q_offset - window + 1) // bkv)
+    return lo, hi
+
+
+def q_block_range(kvi, *, bq: int, bkv: int, nq: int, causal: bool,
+                  window: int, q_offset: int):
+    """[lo, hi] q blocks that see a key of kv block ``kvi`` (the
+    transpose of :func:`kv_block_range`)."""
+    lo, hi = 0, nq - 1
+    if causal:
+        lo = _max(lo, (kvi * bkv - q_offset) // bq)
+    if window > 0:
+        hi = _min(hi, ((kvi + 1) * bkv - 2 - q_offset + window) // bq)
+    return lo, hi
+
+
+def _clamp(i, lo, hi, n):
+    """Block ``i`` moved into the live range [lo, hi] (and the grid), so
+    that a skipped block repeats its neighbour's index and is not
+    fetched."""
+    return jnp.clip(jnp.clip(i, lo, hi), 0, n - 1)
+
+
+_NT = (((1,), (1,)), ((), ()))          # contract the last dims: A @ B^T
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
                   scale: float, causal: bool, window: int, q_offset: int,
-                  bq: int, bkv: int, kv_len: int, return_lse: bool):
+                  bq: int, bkv: int, kv_len: int, nkv: int,
+                  return_lse: bool):
     lse_ref, (m_ref, l_ref, acc_ref) = ((refs[0], refs[1:]) if return_lse
                                         else (None, refs))
     qi = pl.program_id(1)
@@ -61,29 +108,37 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *refs,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, d)
-    k = k_ref[0, 0].astype(jnp.float32)                  # (bkv, d)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())))  # (bq, bkv)
+    # a block outside [lo, hi] holds no key the mask leaves: its scores
+    # would all be NEG_INF, which leaves m, l and acc as they are
+    lo, hi = kv_block_range(qi, bq=bq, bkv=bkv, nkv=nkv, causal=causal,
+                            window=window, q_offset=q_offset)
 
-    q_pos = (qi * bq + q_offset
-             + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0))
-    k_pos = kvi * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
-    mask = _mask(q_pos, k_pos, kv_len=kv_len, causal=causal, window=window)
-    s = jnp.where(mask, s, NEG_INF)
+    @pl.when((kvi >= lo) & (kvi <= hi))
+    def _compute():
+        q = q_ref[0, 0].astype(jnp.float32) * scale          # (bq, d)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (bkv, d)
+        s = jax.lax.dot_general(q, k, _NT)                   # (bq, bkv)
 
-    m_prev = m_ref[:, :1]                             # (bq, 1)
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)
-    p = jnp.where(mask, p, 0.0)                       # exact masked rows
-    alpha = jnp.exp(m_prev - m_new)                   # (bq, 1)
+        q_pos = (qi * bq + q_offset
+                 + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 0))
+        k_pos = kvi * bkv + jax.lax.broadcasted_iota(jnp.int32, (bq, bkv), 1)
+        mask = _mask(q_pos, k_pos, kv_len=kv_len, causal=causal,
+                     window=window)
+        s = jnp.where(mask, s, NEG_INF)
 
-    l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha \
-        + jnp.dot(p, v_ref[0, 0].astype(jnp.float32),
-                  preferred_element_type=jnp.float32)
-    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        m_prev = m_ref[:, :1]                             # (bq, 1)
+        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)
+        p = jnp.where(mask, p, 0.0)                       # exact masked rows
+        alpha = jnp.exp(m_prev - m_new)                   # (bq, 1)
+
+        l_new = alpha * l_ref[:, :1] + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha \
+            + jnp.dot(p, v_ref[0, 0].astype(jnp.float32),
+                      preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
     @pl.when(kvi == pl.num_programs(2) - 1)
     def _flush():
@@ -143,6 +198,27 @@ def _rows_major(x, s_p):
     return x[:, :, None, :]
 
 
+def _q_major_maps(g: _Geometry, hq: int, nkv: int, causal: bool,
+                  window: int):
+    """(q, kv, row) index maps of a grid (b*hq, q blocks, kv blocks), the
+    forward's and dQ's.  The kv map clamps each step into its q block's
+    live range (:func:`kv_block_range`), so that a skipped step repeats
+    its neighbour's block and no copy is issued for it."""
+    def q_map(bh, qi, kvi):
+        return (bh // hq, bh % hq, qi, 0)
+
+    def kv_map(bh, qi, kvi):
+        lo, hi = kv_block_range(qi, bq=g.bq, bkv=g.bkv, nkv=nkv,
+                                causal=causal, window=window,
+                                q_offset=g.q_offset)
+        return (bh // hq, (bh % hq) // g.groups, _clamp(kvi, lo, hi, nkv), 0)
+
+    def row_map(bh, qi, kvi):
+        return (bh // hq, bh % hq, 0, qi)
+
+    return q_map, kv_map, row_map
+
+
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "bq", "bkv", "scale", "q_offset", "interpret",
     "vmem_bytes", "return_lse"))
@@ -166,25 +242,18 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     b, sq, hq, _ = q.shape
     _, skv, _, _ = k.shape
     g = _geometry(q, k, bq, bkv, scale, q_offset)
-    bq, bkv, dp, groups = g.bq, g.bkv, g.dp, g.groups
+    bq, bkv, dp = g.bq, g.bkv, g.dp
+    nkv = g.skv_p // bkv
     qt = _heads_major(q, g.sq_p, dp)
     kt = _heads_major(k, g.skv_p, dp)
     vt = _heads_major(v, g.skv_p, dp)
 
-    grid = (b * hq, g.sq_p // bq, g.skv_p // bkv)
-
-    def q_map(bh, qi, kvi):
-        return (bh // hq, bh % hq, qi, 0)
-
-    def kv_map(bh, qi, kvi):
-        return (bh // hq, (bh % hq) // groups, kvi, 0)
-
-    def row_map(bh, qi, kvi):
-        return (bh // hq, bh % hq, 0, qi)
+    grid = (b * hq, g.sq_p // bq, nkv)
+    q_map, kv_map, row_map = _q_major_maps(g, hq, nkv, causal, window)
 
     kernel = functools.partial(
         _flash_kernel, scale=g.scale, causal=causal, window=window,
-        q_offset=g.q_offset, bq=bq, bkv=bkv, kv_len=skv,
+        q_offset=g.q_offset, bq=bq, bkv=bkv, kv_len=skv, nkv=nkv,
         return_lse=return_lse)
 
     out_specs = pl.BlockSpec((1, 1, bq, dp), q_map)
@@ -224,51 +293,6 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 # Backward: FlashAttention-2 dK/dV and dQ kernels fed by the forward's lse
 # ---------------------------------------------------------------------------
-
-def _min(a, b):
-    both_ints = isinstance(a, int) and isinstance(b, int)
-    return min(a, b) if both_ints else jnp.minimum(a, b)
-
-
-def _max(a, b):
-    both_ints = isinstance(a, int) and isinstance(b, int)
-    return max(a, b) if both_ints else jnp.maximum(a, b)
-
-
-def kv_block_range(qi, *, bq: int, bkv: int, nkv: int, causal: bool,
-                   window: int, q_offset: int):
-    """[lo, hi] kv blocks that q block ``qi`` sees a key of: the causal
-    diagonal bounds ``hi``, the window ``lo``.  Works on ints and traced
-    scalars alike; an empty range has hi < lo."""
-    lo, hi = 0, nkv - 1
-    if causal:
-        hi = _min(hi, (qi * bq + bq - 1 + q_offset) // bkv)
-    if window > 0:
-        lo = _max(lo, (qi * bq + q_offset - window + 1) // bkv)
-    return lo, hi
-
-
-def q_block_range(kvi, *, bq: int, bkv: int, nq: int, causal: bool,
-                  window: int, q_offset: int):
-    """[lo, hi] q blocks that see a key of kv block ``kvi`` (the
-    transpose of :func:`kv_block_range`)."""
-    lo, hi = 0, nq - 1
-    if causal:
-        lo = _max(lo, (kvi * bkv - q_offset) // bq)
-    if window > 0:
-        hi = _min(hi, ((kvi + 1) * bkv - 2 - q_offset + window) // bq)
-    return lo, hi
-
-
-def _clamp(i, lo, hi, n):
-    """Block ``i`` moved into the live range [lo, hi] (and the grid), so
-    that a skipped block repeats its neighbour's index and is not
-    fetched."""
-    return jnp.clip(jnp.clip(i, lo, hi), 0, n - 1)
-
-
-_NT = (((1,), (1,)), ((), ()))          # contract the last dims: A @ B^T
-
 
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, scale: float,
@@ -418,18 +442,9 @@ def flash_attention_bwd(q: jax.Array, k: jax.Array, v: jax.Array,
         interpret=interpret,
     )(qt, kt, vt, dout, lse_r, di_r)
 
-    # dQ: grid (b*hq, q blocks, kv blocks)
-    def dq_q_map(bh, qi, kvi):
-        return (bh // hq, bh % hq, qi, 0)
-
-    def dq_row_map(bh, qi, kvi):
-        return (bh // hq, bh % hq, 0, qi)
-
-    def dq_kv_map(bh, qi, kvi):
-        lo, hi = kv_block_range(qi, bq=bq, bkv=bkv, nkv=nkv, causal=causal,
-                                window=window, q_offset=g.q_offset)
-        return (bh // hq, (bh % hq) // groups, _clamp(kvi, lo, hi, nkv), 0)
-
+    # dQ: grid (b*hq, q blocks, kv blocks), the forward's
+    dq_q_map, dq_kv_map, dq_row_map = _q_major_maps(g, hq, nkv, causal,
+                                                    window)
     q_spec = pl.BlockSpec((1, 1, bq, dp), dq_q_map)
     row_spec = pl.BlockSpec((1, 1, 1, bq), dq_row_map)
     kv_spec = pl.BlockSpec((1, 1, bkv, dp), dq_kv_map)

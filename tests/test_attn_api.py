@@ -400,9 +400,26 @@ def test_flash_plan_records_its_backward(monkeypatch):
     assert len(bw.vmem) == 2 and all(fits_vmem_bytes(v) for v in bw.vmem)
     assert "flash_attention_bwd bq=512 bkv=512, 10 of 16 blocks" \
         in pl.explain()
-    # a window empties whole blocks below the diagonal too
+    # the forward skips the same blocks at its own bq, bkv
+    assert (pl.bq, pl.bkv) == (512, 512)
+    assert pl.blocks == (10, 16) and pl.block_share == 10 / 16
+    assert "  forward  : 10 of 16 blocks computed (62.5%)\n  backward :" \
+        in pl.explain()
+    danube = ops.attn_plan(ops.AttnSpec(group=4, window=4096),
+                           (8, 4096, 4096, 32, 8, 120))
+    assert (danube.bq, danube.bkv, danube.blocks) == (512, 512, (36, 64))
+    # a window empties whole blocks below the diagonal too; this plan's
+    # forward takes q blocks of 1024
     w = ops.attn_plan(ops.AttnSpec(window=512), (1, 2048, 2048, 2, 2, 64))
     assert w.bwd.blocks == (7, 16)
+    assert (w.bq, w.bkv, w.blocks) == (1024, 512, (5, 8))
+    w512 = ops.attn_plan(ops.AttnSpec(window=512, bq=512, bkv=512),
+                         (1, 2048, 2048, 2, 2, 64))
+    assert w512.blocks == (7, 16)
+    # without the causal mask every block is computed
+    full = ops.attn_plan(ops.AttnSpec(causal=False), (1, 2048, 2048, 2, 2, 64))
+    assert full.blocks == (16, 16) and full.block_share == 1.0
+    assert "forward  : 16 of 16 blocks computed (100.0%)" in full.explain()
     rec = telemetry.enable(telemetry.Recorder())
     try:
         q, k, v = _qkv(sq=256, skv=256, hq=4, hkv=2)
@@ -412,6 +429,9 @@ def test_flash_plan_records_its_backward(monkeypatch):
     (ev,) = [e["attrs"] for e in rec.events if e["name"] == "attn.execute"]
     assert (ev["bwd_kernel"], ev["bwd_bq"], ev["bwd_bkv"],
             ev["bwd_block_share"]) == ("flash_attention_bwd", 256, 256, 1.0)
+    assert (ev["bq"], ev["bkv"], ev["block_share"]) == (256, 256, 1.0)
+    (plan_ev,) = [e["attrs"] for e in rec.events if e["name"] == "attn.plan"]
+    assert (plan_ev["block_share"], plan_ev["bwd_block_share"]) == (1.0, 1.0)
 
 
 @pytest.mark.parametrize("mode,shapes,spec,want", [

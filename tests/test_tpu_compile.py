@@ -164,6 +164,43 @@ def test_flash_backward_compiles_for_v5e(one_chip, pallas):
                       for v in (pl.vmem_bytes, *pl.bwd.vmem)}
 
 
+#: one chip's flash forward in each train cell: (shapes, window, the
+#: blocks it computes of all)
+FLASH_FORWARDS = {
+    "smollm_16x2048_h15/5_d64": ((16, 2048, 2048, 15, 5, 64), 0, (10, 16)),
+    "danube_4x4096_h16/4_d120": ((4, 4096, 4096, 16, 4, 120), 4096,
+                                 (36, 64)),
+}
+
+
+@pytest.mark.parametrize("return_lse", [False, True])
+@pytest.mark.parametrize("case", sorted(FLASH_FORWARDS))
+def test_flash_forward_compiles_for_v5e(case, return_lse, one_chip, pallas):
+    """The forward that skips the blocks its mask empties, at each train
+    cell's per-chip shape, with and without its lse, under the scoped
+    VMEM limit of its plan's modeled working set."""
+    from repro.core.memory_model import vmem_limit_bytes
+    from repro.kernels.flash_attention import flash_attention
+    (b, sq, skv, hq, hkv, d), window, blocks = FLASH_FORWARDS[case]
+    pl = ops.attn_plan(ops.AttnSpec(group=hq // hkv, window=window),
+                       (b, sq, skv, hq, hkv, d))
+    assert (pl.kernel, pl.bq, pl.bkv, pl.blocks) == (
+        "flash_attention", 512, 512, blocks)
+
+    def sds(shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+
+    hlo = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, window=window, bq=pl.bq, bkv=pl.bkv,
+        vmem_bytes=pl.vmem_bytes, return_lse=return_lse)).lower(
+        sds((b, sq, hq, d)), sds((b, skv, hkv, d)),
+        sds((b, skv, hkv, d))).compile().as_text()
+    (call,) = [ln for ln in hlo.splitlines() if "tpu_custom_call" in ln]
+    scoped = {int(m) for m in re.findall(
+        r'"scoped_memory_configs":\[\{[^]]*"size":"(\d+)"', call)}
+    assert scoped == {vmem_limit_bytes(pl.vmem_bytes)}
+
+
 #: (m, k, n) of the GEMMs one chip of the danube mesh runs: q and o
 #: projections, k/v, the gated MLP, its down projection, and one chunk
 #: of the lm head (seq 4096 / 8 chunks x 4 rows)
