@@ -10,9 +10,9 @@ no TPU).
 Also writes ``BENCH_gemm.json`` (rows + the fused-vs-unfused SwiGLU
 modeled-HBM ratios + the grouped MoE block with its
 grouped-vs-dense-capacity FLOPs ratio + the plan-cache counters proving
-the DSE resolves once per unique spec+shape + modeled-vs-measured rows
-for the planned attention path); the pallas-interpret CI job uploads it
-as an artifact.
+the DSE resolves once per unique spec+shape + the modeled rows of the
+planned attention path); the pallas-interpret CI job uploads it as an
+artifact.
 """
 
 from __future__ import annotations
@@ -23,7 +23,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro import ops, quant, telemetry
 from repro.core import dse
@@ -31,7 +30,6 @@ from repro.core.bandwidth import estimate
 from repro.core.hardware import TPU_V5E
 from repro.core.tiling import GemmProblem, TileConfig
 from repro.kernels import ref
-from repro.telemetry import report as treport
 
 BENCH_JSON = os.environ.get("REPRO_BENCH_JSON", "BENCH_gemm.json")
 
@@ -327,110 +325,12 @@ def run(report) -> None:
     }
     end_section("grouped")
 
-    # ------------------------------------- model-vs-measured section
-    # Representative decode-shaped specs, executed standalone and
-    # joined with their modeled bytes/roofline time — the measurement
-    # half of the paper's analytic story.  On this CPU host 'achieved'
-    # only compares specs against each other (honesty note in the
-    # report module); the check is that measurement itself works.
-    mvm_plans = [
-        ops.plan(ops.GemmSpec(), (16, 1024, 1024)),
-        ops.plan(ops.GemmSpec(b_quant=True), (16, 1024, 1024)),
-        ops.plan(ops.GemmSpec(gated=True,
-                              epilogue=ops.Epilogue(activation="silu")),
-                 (16, 512, 512)),
-    ]
-    mvm = treport.model_vs_measured(mvm_plans, iters=3)
-    for r in mvm:
-        report.row("gemm", f"model-vs-measured {r['spec']}",
-                   shape=f"{r['m']}x{r['k']}x{r['n']}",
-                   modeled_us=r["t_model_us"],
-                   measured_us=r["t_measured_us"],
-                   achieved=r["achieved"], mode=r["mode"],
-                   ok=r["t_measured_us"] is not None
-                   and r["t_measured_us"] > 0)
-    end_section("model_vs_measured")
-
-    # ------------------------------------------------ autotune section
-    # Re-time the fused-vs-unfused SwiGLU wash with *measured* tiles:
-    # autotuning on (small K — this is a CPU host), every GEMM the two
-    # MLPs plan goes through the top-K sweep, winners persist to the
-    # tuning cache, and the wall-clock is re-taken with the tuned plans.
-    from repro import tune
-    tune.enable(2)
-    try:
-        # fresh lambdas: jit caches traces per function object, and the
-        # fused_mlp/unfused_mlp traces above predate autotuning — the
-        # retrace is what routes every plan through the tuner
-        t_fused_at = _time(jax.jit(lambda v: fused_mlp(v)), x)
-        t_unfused_at = _time(jax.jit(lambda v: unfused_mlp(v)), x)
-        tuned_plans = [{
-            "spec": p.spec.key, "shape": f"{p.m}x{p.k}x{p.n}",
-            "tile": f"{p.tile.strategy} {p.tile.bm}x{p.tile.bk}x"
-                    f"{p.tile.bn}",
-            "source": p.source,
-            "t_measured_us": (round(p.tuned.t_measured_us, 2)
-                              if p.tuned else None),
-            "t_analytic_us": (round(p.tuned.t_analytic_us, 2)
-                              if p.tuned and p.tuned.t_analytic_us
-                              else None),
-            "analytic_tile": p.tuned.analytic_tile if p.tuned else None,
-            "from_cache": p.tuned.from_cache if p.tuned else None,
-        } for p in ops.plans()]
-        winner = "fused" if t_fused_at <= t_unfused_at else "unfused"
-        delta = abs(t_fused_at - t_unfused_at) \
-            / max(min(t_fused_at, t_unfused_at), 1e-12)
-        n_tuned = sum(1 for p in tuned_plans if p["source"] == "tuned")
-        autotune_section = {
-            "k": 2,
-            "fused_us": round(t_fused_at * 1e6, 1),
-            "unfused_us": round(t_unfused_at * 1e6, 1),
-            "fused_us_analytic_tiles": round(t_fused * 1e6, 1),
-            "unfused_us_analytic_tiles": round(t_unfused * 1e6, 1),
-            "winner": winner,
-            "measured_delta": round(delta, 4),
-            "plans": tuned_plans,
-            "tuning_cache": tune.tuning_cache_info()._asdict(),
-            "cache_path": tune.cache_path(),
-        }
-        report.row("gemm", "swiglu autotuned wall-clock",
-                   fused_us=f"{t_fused_at*1e6:.0f}",
-                   unfused_us=f"{t_unfused_at*1e6:.0f}",
-                   winner=winner, delta=f"{delta:.2f}x",
-                   tuned=f"{n_tuned}/{len(tuned_plans)}",
-                   ok=n_tuned > 0)
-    finally:
-        tune.disable()
-    end_section("autotune")
-
-    # ---------------------------------------------- calibration section
-    # Regress every measured sample the tuner just persisted against its
-    # modeled HBM bytes + flops: effective per-mode bandwidth/compute
-    # constants with R².  On this CPU host the constants describe the
-    # host, not a TPU — that is exactly what makes them useful for
-    # re-ranking tiles here and honest in the report.
-    fits = tune.calibrate.fit()
-    calibration_section = {mode: c.as_dict() for mode, c in fits.items()}
-    for mode, c in fits.items():
-        report.row("gemm", f"calibration fit [{mode}]",
-                   n=c.n_samples,
-                   eff_bw=("-" if c.hbm_bw is None
-                           else f"{c.hbm_bw/1e9:.2f}GB/s"),
-                   eff_flops=("-" if c.peak_flops is None
-                              else f"{c.peak_flops/1e9:.1f}GF/s"),
-                   t0_us=f"{c.t0_us:.1f}", r2=f"{c.r2:.4f}",
-                   ok=c.n_samples >= 3)
-
     # ------------------------------------------------ attention section
-    # Same treatment for the AttnSpec -> attn_plan -> attn_execute path:
-    # representative prefill/decode/paged specs planned through the
-    # attention DSE and executed standalone, the measured median joined
-    # with the plan's modeled bytes/roofline time.  Ref dispatch pinned
-    # for the timing rows (the GEMM model-vs-measured honesty note
-    # applies), plus one interpret-parity row proving the planned
-    # flash-decode body agrees with its XLA oracle through the same
-    # plan/execute entrypoints the serve loop uses.
-    from repro.tune import measure_attn_plan
+    # The AttnSpec -> attn_plan path: representative prefill/decode/
+    # paged specs planned through the attention DSE with their modeled
+    # bytes/roofline time, plus one interpret-parity row proving the
+    # planned flash-decode body agrees with its XLA oracle through the
+    # same plan/execute entrypoints the serve loop uses.
     ops.attn_plan_cache_clear()
     attn_cases = [
         ("prefill mha causal b1s512 d64",
@@ -448,33 +348,25 @@ def run(report) -> None:
     prev_mode = os.environ.get("REPRO_KERNELS")
     os.environ["REPRO_KERNELS"] = "ref"
     try:
-        rng = np.random.default_rng(0)
         for label, spec_a, shapes_a in attn_cases:
             pl_a = ops.attn_plan(spec_a, shapes_a)
-            meas = measure_attn_plan(pl_a, iters=3, warmup=1, rng=rng)
-            t_us = meas.median_s * 1e6
             t_model_us = pl_a.traffic.t_model * 1e6
             attn_rows.append({
                 "spec": pl_a.spec.key, "shape": pl_a.shape_key,
                 "kernel": pl_a.kernel,
                 "blocks": (f"{pl_a.bq or '-'}x{pl_a.bkv or '-'}"
                            if pl_a.bq or pl_a.bkv else None),
-                "source": pl_a.source,
                 "hbm_mib": round(pl_a.hbm_bytes / 2**20, 3),
                 "flops": pl_a.flops,
                 "bound": pl_a.traffic.bound,
                 "t_model_us": round(t_model_us, 2),
-                "t_measured_us": round(t_us, 2),
-                "spread": round(meas.spread, 4),
-                "mode": "ref",
                 "fallback_reason": pl_a.fallback_reason,
             })
-            report.row("gemm", f"attn model-vs-measured {label}",
+            report.row("gemm", f"attn plan {label}",
                        kernel=pl_a.kernel,
                        modeled_us=f"{t_model_us:.1f}",
-                       measured_us=f"{t_us:.0f}",
                        hbm_mib=f"{pl_a.hbm_bytes/2**20:.1f}",
-                       ok=t_us > 0)
+                       ok=t_model_us > 0)
     finally:
         if prev_mode is None:
             os.environ.pop("REPRO_KERNELS", None)
@@ -515,7 +407,7 @@ def run(report) -> None:
         else:
             os.environ["REPRO_KERNELS"] = prev_mode
     attn_section = {
-        "model_vs_measured": attn_rows,
+        "plans": attn_rows,
         "plan_cache": attn_cache._asdict(),
         "interpret_flash_decode_max_abs_err": err_a,
     }
@@ -524,13 +416,9 @@ def run(report) -> None:
     payload = {"rows": report.rows, "swiglu_fused_hbm": ratios,
                "attn": attn_section,
                "grouped": grouped_section,
-               "autotune": autotune_section,
-               "calibration": calibration_section,
                "w8a16_decode_hbm_ratio": round(hbm8 / hbm16, 4),
                "plan_cache": info._asdict(),
-               "plan_cache_sections": section_stats,
-               "model_vs_measured": mvm,
-               "model_vs_measured_summary": treport.summarize(mvm)}
+               "plan_cache_sections": section_stats}
     if telemetry.enabled():
         payload["telemetry_snapshot"] = telemetry.snapshot()
     with open(BENCH_JSON, "w") as f:

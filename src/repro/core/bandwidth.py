@@ -4,16 +4,8 @@ The paper computes, per design, the worst-case off-chip bytes needed to
 sustain the accelerator's native throughput and *gates* the DSE on the
 device's DRAM bandwidth.  Here the 'off-chip' level is HBM and the gate is
 the roofline: a tiling whose HBM traffic pushes the memory term above the
-compute term is memory-bound and ranked accordingly.
-
-The roofline rates default to the chip's datasheet constants, but a
-measured :class:`Calibration` (fitted by :mod:`repro.tune.calibrate`
-from the tuning cache's samples) can override them process-wide via
-:func:`set_calibration` — then every ``estimate()`` (and through it the
-DSE ranking and ``roofline.analyze``) prices designs at the *effective*
-rates this host actually achieves.  ``calibration_version()`` increments
-on every change so downstream caches (``dse._solve_cached``) key on it
-instead of serving pre-calibration answers.
+compute term is memory-bound and ranked accordingly.  The roofline rates
+are the chip's datasheet constants.
 """
 
 from __future__ import annotations
@@ -30,52 +22,11 @@ from repro.core.tiling import (
 )
 
 
-@dataclasses.dataclass(frozen=True)
-class Calibration:
-    """Measured effective rates overriding a chip's datasheet constants
-    (``None`` fields keep the chip value)."""
-
-    hbm_bw: Optional[float] = None           # bytes/s
-    peak_bf16_flops: Optional[float] = None  # flop/s
-    peak_int8_ops: Optional[float] = None    # op/s
-    source: str = ""
-
-
-_calibration: Optional[Calibration] = None
-_cal_version: int = 0
-
-
-def set_calibration(cal: Optional[Calibration]) -> None:
-    """Install (or, with ``None``, drop) measured effective constants.
-    Explicit opt-in only — callers that cache anything priced by
-    ``estimate()`` must key on :func:`calibration_version`."""
-    global _calibration, _cal_version
-    _calibration = cal
-    _cal_version += 1
-
-
-def clear_calibration() -> None:
-    set_calibration(None)
-
-
-def get_calibration() -> Optional[Calibration]:
-    return _calibration
-
-
-def calibration_version() -> int:
-    return _cal_version
-
-
 def effective_rates(chip: TPUChip, int8: bool) -> tuple:
-    """(peak flop/s, HBM bytes/s) after any installed calibration."""
+    """(peak flop/s, HBM bytes/s): the int8 MXU rate when both operands
+    are 8-bit, else the bf16 rate."""
     peak = chip.peak_int8_ops if int8 else chip.peak_bf16_flops
-    bw = chip.hbm_bw
-    cal = _calibration
-    if cal is not None:
-        over = cal.peak_int8_ops if int8 else cal.peak_bf16_flops
-        peak = over or peak
-        bw = cal.hbm_bw or bw
-    return peak, bw
+    return peak, chip.hbm_bw
 
 
 @dataclasses.dataclass(frozen=True)

@@ -23,11 +23,7 @@ import dataclasses
 import functools
 from typing import List, Optional, Sequence, Tuple
 
-from repro.core.bandwidth import (
-    TrafficEstimate,
-    calibration_version,
-    estimate,
-)
+from repro.core.bandwidth import TrafficEstimate, estimate
 from repro.core.hardware import TPU_V5E, TPUChip
 from repro.core.memory_model import (
     fits_vmem,
@@ -88,8 +84,7 @@ def _lane_candidates(dim: int) -> Sequence[int]:
 def _solve_cached(m: int, k: int, n: int, a_dtype: str, b_dtype: str,
                   out_dtype: str, acc_dtype: str, epilogue: str,
                   n_b_operands: int, n_groups: int, chip_name: str,
-                  top: int, cal_version: int
-                  ) -> Tuple["TileDesign", ...]:
+                  top: int) -> Tuple["TileDesign", ...]:
     assert chip_name == TPU_V5E.name, "single-target build"
     chip = TPU_V5E
     p = GemmProblem(m, k, n, a_dtype, out_dtype, acc_dtype, b_dtype,
@@ -126,14 +121,10 @@ def _solve_cached(m: int, k: int, n: int, a_dtype: str, b_dtype: str,
 
 def solve(p: GemmProblem, chip: TPUChip = TPU_V5E, top: int = 10
           ) -> List[TileDesign]:
-    """Ranked tiling designs for a GEMM problem.  The memo key includes
-    the cost-model calibration version: applying measured constants
-    (``repro.tune.calibrate.apply``) re-ranks instead of serving stale
-    pre-calibration answers."""
+    """Ranked tiling designs for a GEMM problem."""
     return list(_solve_cached(p.m, p.k, p.n, p.a_dtype, p.b_dtype,
                               p.out_dtype, p.acc_dtype, p.epilogue,
-                              p.n_b_operands, p.n_groups, chip.name, top,
-                              calibration_version()))
+                              p.n_b_operands, p.n_groups, chip.name, top))
 
 
 def best_tile(m: int, k: int, n: int, in_dtype: str = "bfloat16",

@@ -137,13 +137,8 @@ class RooflineReport:
 def analyze(compiled, *, chip: TPUChip = TPU_V5E, int8: bool = False,
             model_flops_per_device: Optional[float] = None,
             hlo_text: Optional[str] = None) -> RooflineReport:
-    """Build the 3-term roofline from a compiled (SPMD) executable.
-
-    Compute/memory rates honor any installed cost-model calibration
-    (:func:`repro.core.bandwidth.set_calibration` — measured effective
-    constants fitted by ``repro.tune.calibrate``); the collective term
-    keeps the datasheet ICI rate (no calibration source measures it).
-    """
+    """Build the 3-term roofline from a compiled (SPMD) executable, at
+    the chip's datasheet compute, HBM and ICI rates."""
     from repro.core.bandwidth import effective_rates
     cost = compiled.cost_analysis()
     text = hlo_text if hlo_text is not None else compiled.as_text()

@@ -29,12 +29,13 @@ module is that pipeline as the reproduction's only GEMM entrypoint:
   six hand-specialized VJP wrappers the pre-redesign dispatch layer
   accreted.  :func:`gemm` is the one-shot composition of the three.
 
-Dispatch policy (the hardware-adaptation contract) is unchanged: Pallas
-kernels on TPU (or under ``REPRO_KERNELS=interpret``), the mathematically
-identical pure-jnp references elsewhere — but the *plan* is computed the
-same way everywhere, so the cost model stays introspectable on hosts
-with no TPU.  The legacy ``repro.kernels.ops`` entrypoints are deprecated
-shims over this module.
+Dispatch policy (the hardware-adaptation contract, in
+:mod:`repro.kernels.planner` with the plan cache): Pallas kernels on TPU
+(or under ``REPRO_KERNELS=interpret``), the mathematically identical
+pure-jnp references elsewhere — but the *plan* is computed the same way
+everywhere, so the cost model stays introspectable on hosts with no TPU.
+The legacy ``repro.kernels.ops`` entrypoints are deprecated shims over
+this module.
 """
 
 from __future__ import annotations
@@ -42,12 +43,10 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import os
-from typing import NamedTuple, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro import quant as _quant
 from repro import telemetry
@@ -65,29 +64,15 @@ from repro.kernels.gemm_aie import gemm_aie
 from repro.kernels.gemm_gated import gemm_gated as _gemm_gated_kernel
 from repro.kernels.gemm_grouped import gemm_grouped as _gemm_grouped_kernel
 from repro.kernels.gemm_tb import feasible_bk, gemm_tb
-
-
-# ---------------------------------------------------------------------------
-# Kernel-mode selection (shared by every kernel entrypoint)
-# ---------------------------------------------------------------------------
-
-def _mode() -> str:
-    env = os.environ.get("REPRO_KERNELS", "auto")
-    if env in ("interpret", "ref", "pallas"):
-        return env
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
-
-
-def use_pallas() -> bool:
-    return _mode() in ("pallas", "interpret")
-
-
-def _interpret() -> bool:
-    return _mode() == "interpret"
-
-
-def _dtname(dt) -> str:
-    return jnp.dtype(dt).name
+from repro.kernels.planner import (  # noqa: F401  (re-exported names)
+    PlanCache,
+    PlanCacheInfo,
+    dispatch_mode as _mode,
+    dtname as _dtname,
+    float0 as _float0,
+    interpret as _interpret,
+    use_pallas,
+)
 
 
 def _is_quant(b) -> bool:
@@ -120,8 +105,7 @@ class GemmSpec:
       can report the padding-flops delta vs the dense E*capacity
       formulation.  Output-stationary only ('tb' rejected), single-B
       (``gated`` rejected), epilogue limited to per-expert bias +
-      activation, and measured autotuning is skipped (the tuner's
-      measurement harness is dense-only) — plans stay analytic.
+      activation.
     * ``epilogue`` — declarative bias / activation / residual /
       out-quant fused into the kernel flush (an
       :class:`~repro.kernels.epilogue.Epilogue`, or its key string).
@@ -130,13 +114,6 @@ class GemmSpec:
       an infeasible explicit tile raises at plan time.
     * ``out_dtype`` — ``None`` resolves to ``a_dtype`` (int8 when the
       epilogue quantizes the output).
-    * ``tune`` — measured autotuning (:mod:`repro.tune`): ``True`` makes
-      ``plan()`` consult the persistent tuning cache and, on a miss,
-      time the top-K analytic candidates on-device and pick the measured
-      winner; ``False`` forces the purely analytic DSE; ``None``
-      (default) defers to ``repro.tune.enable()`` / ``REPRO_AUTOTUNE``.
-      Excluded from :attr:`key` so tuning-cache entries join with the
-      same spec regardless of *how* tuning was switched on.
 
     Frozen and hashable: specs key the plan cache, ride jit static
     arguments, and serialize their intent into ``GemmProblem`` for the
@@ -152,7 +129,6 @@ class GemmSpec:
     out_dtype: Optional[str] = None
     strategy: Optional[str] = None
     tile: Optional[TileConfig] = None
-    tune: Optional[bool] = None
 
     def __post_init__(self):
         object.__setattr__(self, "a_dtype", _dtname(self.a_dtype))
@@ -204,8 +180,8 @@ class GemmSpec:
 
     @property
     def key(self) -> str:
-        """Compact canonical string — the join key telemetry events and
-        the model-vs-measured report use for this spec."""
+        """Compact canonical string — the join key telemetry events use
+        for this spec."""
         s = f"{self.a_dtype}x{self.b_dtype}"
         if self.b_quant:
             s += "{q}"
@@ -228,8 +204,7 @@ class GemmSpec:
                      activation: Optional[str] = None, residual=None,
                      out_scale=None, strategy: Optional[str] = None,
                      tile: Optional[TileConfig] = None,
-                     out_dtype=None,
-                     tune: Optional[bool] = None) -> "GemmSpec":
+                     out_dtype=None) -> "GemmSpec":
         """Spec inferred from concrete operands (arrays or ``{"q",
         "scale"}`` weight structs) plus the optional epilogue set — what
         the one-shot :func:`gemm` and the legacy shims build."""
@@ -250,7 +225,7 @@ class GemmSpec:
             b_dtype="int8" if bq else _dtname(b.dtype),
             b_quant=bq, gated=gated, epilogue=ep,
             out_dtype=None if out_dtype is None else _dtname(out_dtype),
-            strategy=strategy, tile=tile, tune=tune)
+            strategy=strategy, tile=tile)
 
 
 def gemm_shapes(a, b) -> Tuple[int, int, int]:
@@ -280,21 +255,6 @@ def gemm_grouped_shapes(a, b, dense_rows: Optional[int] = None
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
-class TunedInfo:
-    """The measured-autotuning record riding a tuned plan: the winner's
-    measured time (median, with spread), the analytic first choice it
-    was compared against, and whether the answer came from the
-    persistent cache (zero re-measurement) or a fresh top-K sweep."""
-
-    t_measured_us: float            # winner median wall-clock
-    spread: float                   # (max-min)/median of kept samples
-    t_analytic_us: Optional[float]  # measured time of the DSE's rank-0
-    analytic_tile: str              # e.g. "aie 16x512x512"
-    k_searched: int
-    from_cache: bool
-
-
-@dataclasses.dataclass(frozen=True)
 class GemmPlan:
     """One resolved execution decision: spec x (m, k, n) -> strategy,
     tile and the modeled costs the DSE ranked it by.  Frozen/hashable so
@@ -309,15 +269,8 @@ class GemmPlan:
     traffic: TrafficEstimate
     vmem: VmemFootprint
     fallback_reason: Optional[str] = None
-    tuned: Optional[TunedInfo] = None
     n_groups: int = 0           # grouped family: expert-group count E
     dense_rows: int = 0         # ... and the dense E*capacity row count
-
-    @property
-    def source(self) -> str:
-        """How the tile was chosen: ``'tuned'`` (measured winner from
-        the autotuner) or ``'analytic'`` (cost-model DSE)."""
-        return "tuned" if self.tuned is not None else "analytic"
 
     @property
     def hbm_bytes(self) -> float:
@@ -387,63 +340,30 @@ class GemmPlan:
                 f"  padding  : {self.flops / 1e9:.2f} GFLOP executed vs "
                 f"{dense_flops / 1e9:.2f} dense-capacity "
                 f"({saved:+.0%} saved)"))
-        if self.tuned is not None:
-            ti = self.tuned
-            t_model_us = self.traffic.t_model * 1e6
-            src = (f"  source   : tuned ({'cache' if ti.from_cache else f'measured top-{ti.k_searched}'})  "
-                   f"{ti.t_measured_us:.1f} us measured vs "
-                   f"{t_model_us:.1f} us modeled "
-                   f"({ti.t_measured_us / t_model_us:.1f}x model, "
-                   f"spread {ti.spread:.0%})")
-            lines.append(src)
-            if ti.t_analytic_us is not None \
-                    and ti.analytic_tile != f"{t.strategy} {t.bm}x{t.bk}x{t.bn}":
-                lines.append(
-                    f"             analytic first choice "
-                    f"{ti.analytic_tile} measured "
-                    f"{ti.t_analytic_us:.1f} us")
-        else:
-            lines.append("  source   : analytic")
         if self.fallback_reason:
             lines.append(f"  fallback : {self.fallback_reason}")
         return "\n".join(lines)
 
 
-class PlanCacheInfo(NamedTuple):
-    entries: int
-    hits: int
-    misses: int
+def _plan_event(pl: GemmPlan, cache: str) -> None:
+    """One telemetry event per plan() call: the full decision record —
+    spec key, chosen strategy/tile, modeled HBM/VMEM bytes, flops,
+    roofline verdict, cache hit/miss, and any fallback reason."""
+    t = pl.tile
+    telemetry.event(
+        "gemm.plan", cache=cache, spec=pl.spec.key,
+        m=pl.m, k=pl.k, n=pl.n, strategy=t.strategy,
+        tile=f"{t.bm}x{t.bk}x{t.bn}", hbm_bytes=pl.hbm_bytes,
+        vmem_bytes=pl.vmem_bytes, flops=pl.flops,
+        t_model_us=pl.traffic.t_model * 1e6, bound=pl.traffic.bound,
+        fallback_reason=pl.fallback_reason)
 
 
-_plan_cache: dict = {}
-_executed: set = set()          # plan keys whose execute() already traced
-_plan_hits = 0
-_plan_misses = 0
-
-
-def plan_cache_info() -> PlanCacheInfo:
-    """(entries, hits, misses) of the spec+shape plan cache — repeated-
-    shape workloads should show DSE resolution ran once per unique
-    (spec, shape)."""
-    return PlanCacheInfo(len(_plan_cache), _plan_hits, _plan_misses)
-
-
-def plan_cache_clear() -> None:
-    """Drop every cached plan and zero the hit/miss counters (tests that
-    monkeypatch the DSE or feasibility checks must call this, or stale
-    plans computed under different rules leak between tests; benchmark
-    sections call it so per-section hit/miss counts start clean)."""
-    global _plan_hits, _plan_misses
-    _plan_cache.clear()
-    _executed.clear()
-    _plan_hits = 0
-    _plan_misses = 0
-
-
-def plans() -> Tuple[GemmPlan, ...]:
-    """Every plan resolved so far (insertion order) — what
-    ``repro-dryrun --explain`` dumps after lowering a model."""
-    return tuple(_plan_cache.values())
+#: GemmPlans by (spec, m, k, n, E, dense_rows)
+_PLANS = PlanCache("gemm", _plan_event)
+plan_cache_info = _PLANS.info
+plan_cache_clear = _PLANS.clear
+plans = _PLANS.values
 
 
 def _clamp_tile(tile: TileConfig, m: int, k: int, n: int) -> TileConfig:
@@ -479,7 +399,6 @@ def plan(spec: GemmSpec, shapes: Tuple[int, ...]) -> GemmPlan:
     the DSE (or a validated user override) plus the modeled costs —
     exactly once per (spec, shape) key.  Grouped specs take the extended
     shapes ``(m, k, n, E[, dense_rows])`` (:func:`gemm_grouped_shapes`)."""
-    global _plan_hits, _plan_misses
     shapes = tuple(int(x) for x in shapes)
     if spec.grouped:
         if len(shapes) not in (4, 5):
@@ -496,46 +415,14 @@ def plan(spec: GemmSpec, shapes: Tuple[int, ...]) -> GemmPlan:
                 f"a dense spec plans with (m, k, n) shapes — got {shapes}")
         m, k, n = shapes
         e, dense_rows = 0, 0
-    key = (spec, m, k, n, e, dense_rows)
-    cached = _plan_cache.get(key)
-    if cached is not None:
-        _plan_hits += 1
-        if telemetry.enabled():
-            _plan_event(cached, "hit")
-        return cached
-    _plan_misses += 1
-    resolved = _resolve(spec, m, k, n, e, dense_rows)
-    _plan_cache[key] = resolved
-    if telemetry.enabled():
-        _plan_event(resolved, "miss")
-    return resolved
-
-
-def _plan_event(pl: "GemmPlan", cache: str) -> None:
-    """One telemetry event per plan() call: the full decision record —
-    spec key, chosen strategy/tile, modeled HBM/VMEM bytes, flops,
-    roofline verdict, cache hit/miss, and any fallback reason."""
-    t = pl.tile
-    telemetry.counter(f"gemm.plan_cache.{cache}").add(1)
-    tuned = pl.tuned
-    t_model_us = pl.traffic.t_model * 1e6
-    telemetry.event(
-        "gemm.plan", cache=cache, spec=pl.spec.key,
-        m=pl.m, k=pl.k, n=pl.n, strategy=t.strategy,
-        tile=f"{t.bm}x{t.bk}x{t.bn}", hbm_bytes=pl.hbm_bytes,
-        vmem_bytes=pl.vmem_bytes, flops=pl.flops,
-        t_model_us=t_model_us, bound=pl.traffic.bound,
-        source=pl.source,
-        t_measured_us=tuned.t_measured_us if tuned else None,
-        measured_vs_model=(tuned.t_measured_us / t_model_us
-                           if tuned and t_model_us else None),
-        fallback_reason=pl.fallback_reason)
+    return _PLANS.get_or_resolve(
+        (spec, m, k, n, e, dense_rows),
+        lambda: _resolve(spec, m, k, n, e, dense_rows))
 
 
 def _problem_for(spec: GemmSpec, m: int, k: int, n: int,
                  n_groups: int = 0) -> GemmProblem:
-    """The cost-model problem a spec resolves to at concrete shapes —
-    shared by ``plan()``, :func:`solve_topk` and the autotuner."""
+    """The cost-model problem a spec resolves to at concrete shapes."""
     ep = spec.epilogue
     out_dtype = spec.out_dtype or ("int8" if ep.out_quant
                                    else spec.a_dtype)
@@ -545,35 +432,10 @@ def _problem_for(spec: GemmSpec, m: int, k: int, n: int,
                        n_groups if spec.grouped else 0)
 
 
-def solve_topk(spec: GemmSpec, shapes: Tuple[int, int, int],
-               k: int = 5) -> Tuple:
-    """The ranked analytic tile candidates the autotuner sweeps for
-    ``spec`` at ``shapes`` — a thin introspection wrapper over
-    ``dse.solve`` (:class:`repro.core.dse.TileDesign` rows, best first,
-    restricted to the spec's strategy when one is pinned; a restricted
-    spec can return fewer than ``k`` rows)."""
-    m, kk, n = (int(x) for x in shapes[:3])
-    problem = _problem_for(spec, m, kk, n,
-                           int(shapes[3]) if len(shapes) > 3 else 0)
-    k = max(int(k), 1)
-    designs = dse.solve(problem, top=k)
-    if spec.strategy is not None:
-        designs = [d for d in designs if d.tile.strategy == spec.strategy]
-    return tuple(designs[:k])
-
-
-def _tune_enabled(spec: GemmSpec) -> bool:
-    if spec.tune is not None:
-        return spec.tune
-    from repro.tune import autotune as _autotune
-    return _autotune.is_enabled(None)
-
-
 def _resolve(spec: GemmSpec, m: int, k: int, n: int, n_groups: int = 0,
              dense_rows: int = 0) -> GemmPlan:
     problem = _problem_for(spec, m, k, n, n_groups)
     fallback = None
-    tuned = None
     if spec.tile is not None:
         # explicit override: honored verbatim (quantized B included) —
         # but an infeasible tile raises instead of silently re-routing
@@ -584,54 +446,30 @@ def _resolve(spec: GemmSpec, m: int, k: int, n: int, n_groups: int = 0,
                 f"explicit tile {tile.strategy} {tile.bm}x{tile.bk}x"
                 f"{tile.bn} is infeasible for {problem}: {err}")
     else:
-        tile = None
-        # grouped specs stay analytic: the tuner's measurement harness
-        # builds dense operands and would mis-time the ragged sweep
-        if _tune_enabled(spec) and not spec.grouped:
-            # measured autotuning: the persistent tuning cache first,
-            # then a top-K measured sweep; any degradation (over-budget
-            # problem, stale/corrupt cache, measurement failure) falls
-            # through to the analytic DSE below — never an exception
-            from repro import tune as _tune
-            found = _tune.lookup_or_search(spec, (m, k, n), problem)
-            if found is not None:
-                cand, tuned = found
-                cand = _clamp_tile(cand, m, k, n)
-                err = _infeasible_reason(cand, problem)
-                if err:
-                    # e.g. a cache entry measured on a different host
-                    fallback = (f"tuned tile {cand.strategy} {cand.bm}x"
-                                f"{cand.bk}x{cand.bn} infeasible here "
-                                f"({err}); re-resolved analytically")
-                    tuned = None
-                else:
-                    tile = cand
-        if tile is None:
-            designs = dse.solve(problem)
-            chosen = next((d for d in designs
-                           if spec.strategy in (None, d.tile.strategy)),
-                          None)
-            if chosen is None:
+        designs = dse.solve(problem)
+        chosen = next((d for d in designs
+                       if spec.strategy in (None, d.tile.strategy)), None)
+        if chosen is None:
+            raise ValueError(
+                f"no feasible {spec.strategy!r} tiling for {problem}")
+        tile = _clamp_tile(chosen.tile, m, k, n)
+        err = _infeasible_reason(tile, problem)
+        if err:
+            # the DSE winner can only fail the stricter post-clamp
+            # tb recheck; fall back to the best 'aie' design
+            aie = next((d for d in designs
+                        if d.tile.strategy == "aie"), None)
+            if aie is None:
                 raise ValueError(
-                    f"no feasible {spec.strategy!r} tiling for {problem}")
-            tile = _clamp_tile(chosen.tile, m, k, n)
-            err = _infeasible_reason(tile, problem)
-            if err:
-                # the DSE winner can only fail the stricter post-clamp
-                # tb recheck; fall back to the best 'aie' design
-                aie = next((d for d in designs
-                            if d.tile.strategy == "aie"), None)
-                if aie is None:
-                    raise ValueError(
-                        f"no feasible tiling for {problem}: {err}")
-                fallback = (f"tb tile {tile.bm}x{tile.bk}x{tile.bn} "
-                            f"infeasible ({err}); fell back to the "
-                            "DSE's aie winner")
-                tile = _clamp_tile(aie.tile, m, k, n)
+                    f"no feasible tiling for {problem}: {err}")
+            fallback = (f"tb tile {tile.bm}x{tile.bk}x{tile.bn} "
+                        f"infeasible ({err}); fell back to the "
+                        "DSE's aie winner")
+            tile = _clamp_tile(aie.tile, m, k, n)
     traffic = estimate(tile, problem, TPU_V5E)
     vmem = vmem_footprint(tile, problem, TPU_V5E)
     return GemmPlan(spec, m, k, n, problem, tile, traffic, vmem,
-                    fallback, tuned, n_groups, dense_rows)
+                    fallback, n_groups, dense_rows)
 
 
 # ---------------------------------------------------------------------------
@@ -800,10 +638,6 @@ def _dispatch(pl: GemmPlan, a, b, b_scale, b2, b2_scale, bias, residual,
 # The ONE custom VJP of the GEMM family
 # ---------------------------------------------------------------------------
 
-def _float0(x):
-    return np.zeros(x.shape, jax.dtypes.float0)
-
-
 def _act_bwd(activation: Optional[str], z: jax.Array, g: jax.Array
              ) -> jax.Array:
     """dL/dz given dL/d(act(z)) — the unfused-composition backward."""
@@ -816,13 +650,10 @@ def _act_bwd(activation: Optional[str], z: jax.Array, g: jax.Array
 def _plain(a: jax.Array, b: jax.Array, b_scale, out_dtype,
            strategy: Optional[str] = None) -> jax.Array:
     """A planned plain GEMM (no epilogue) — the recompute primitive the
-    generic backward is composed from.  Backward GEMMs pin
-    ``tune=False``: the autotuner measures forward plans only, and a
-    measurement pass must never trigger nested searches from its own
-    recompute GEMMs."""
+    generic backward is composed from."""
     spec = GemmSpec(a_dtype=a.dtype, b_dtype=b.dtype,
                     b_quant=b_scale is not None, out_dtype=out_dtype,
-                    strategy=strategy, tune=False)
+                    strategy=strategy)
     pl = plan(spec, (a.shape[0], a.shape[1], b.shape[1]))
     return _gemm_core(pl, a, b, b_scale, None, None, None, None)
 
@@ -931,11 +762,11 @@ def _group_rows(sizes: jax.Array, m: int):
 
 def _grouped_plain(a, b, b_scale, sizes, out_dtype) -> jax.Array:
     """A planned plain grouped GEMM — the recompute/backward primitive
-    (``tune=False`` like ``_plain``; dense_rows defaults to m, so
-    internal plans claim no padding savings)."""
+    (dense_rows defaults to m, so internal plans claim no padding
+    savings)."""
     spec = GemmSpec(a_dtype=a.dtype, b_dtype=b.dtype,
                     b_quant=b_scale is not None, grouped=True,
-                    out_dtype=out_dtype, tune=False)
+                    out_dtype=out_dtype)
     pl = plan(spec, (a.shape[0], a.shape[1], b.shape[2], b.shape[0]))
     return _grouped_core(pl, a, b, b_scale, sizes, None)
 
@@ -1009,13 +840,8 @@ def _execute_event(pl: GemmPlan) -> None:
     if not telemetry.enabled():
         return
     spec = pl.spec
-    ek = (spec, pl.m, pl.k, pl.n)
-    if ek in _executed:
+    if not _PLANS.first_execute((spec, pl.m, pl.k, pl.n)):
         return
-    # first trace of this plan only: jitted callers re-enter execute()
-    # once per compilation, eager callers every call — the dedup keeps
-    # the event stream one record per plan
-    _executed.add(ek)
     telemetry.event(
         "gemm.execute", spec=spec.key, m=pl.m, k=pl.k, n=pl.n,
         strategy=pl.tile.strategy, mode=_mode(),
@@ -1147,7 +973,7 @@ def execute(pl: GemmPlan, a: jax.Array, b, *, b2=None,
             jax.lax.stop_gradient(a2), axis=-1)
         sub = dataclasses.replace(spec, a_dtype="int8",
                                   epilogue=Epilogue(),
-                                  out_dtype="float32", tune=False)
+                                  out_dtype="float32")
         acc = _gemm_core(plan(sub, (pl.m, pl.k, pl.n)), a_q, b, b_scale,
                          None, None, None, None)
         out = acc * a_s
@@ -1172,8 +998,7 @@ def gemm(a: jax.Array, b, *, b2=None, bias: Optional[jax.Array] = None,
          activation: Optional[str] = None,
          residual: Optional[jax.Array] = None, out_scale=None,
          strategy: Optional[str] = None,
-         tile: Optional[TileConfig] = None, out_dtype=None,
-         tune: Optional[bool] = None) -> jax.Array:
+         tile: Optional[TileConfig] = None, out_dtype=None) -> jax.Array:
     """The one-shot planned GEMM: ``spec -> plan -> execute`` in a
     single call.
 
@@ -1191,8 +1016,7 @@ def gemm(a: jax.Array, b, *, b2=None, bias: Optional[jax.Array] = None,
     spec = GemmSpec.for_operands(a, b, b2, bias=bias,
                                  activation=activation, residual=residual,
                                  out_scale=out_scale, strategy=strategy,
-                                 tile=tile, out_dtype=out_dtype,
-                                 tune=tune)
+                                 tile=tile, out_dtype=out_dtype)
     pl = plan(spec, gemm_shapes(a, b))
     return execute(pl, a, b, b2=b2, bias=bias, residual=residual,
                    out_scale=out_scale)

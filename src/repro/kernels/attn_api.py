@@ -4,7 +4,7 @@ the second hot-spot.
 Mirrors :mod:`repro.kernels.api` exactly: a frozen, hashable
 :class:`AttnSpec` describes *what* attention is being asked for
 (prefill vs decode vs paged-decode, causal/window, GQA ratio,
-per-operand dtypes, the future KV-quant hook); :func:`attn_plan`
+per-operand dtypes); :func:`attn_plan`
 resolves it at concrete shapes into an :class:`AttnPlan` — the kernel
 family (``flash_attention`` / ``attention_blocked`` / ``flash_decode``
 / ``flash_decode_paged`` / the XLA reference paths) **and** its block
@@ -19,13 +19,11 @@ pool reads via :func:`repro.core.bandwidth.decode_kv_bytes`); and
 the log-sum-exp its forward rule saves; every other family recomputes
 through its differentiable XLA composition.
 
-Plans are cached per (spec, shape, dispatch mode) with hit/miss
-counters, emit ``attn.plan`` telemetry events with the full modeled
-decision record, print themselves via :meth:`AttnPlan.explain` (what
-``repro-dryrun --explain`` shows next to the GEMM plans), and — when
-autotuning is enabled — route their block choice through the measured
-top-K search in :mod:`repro.tune.autotune` and its persistent
-``"attn|..."``-keyed cache namespace.
+Plans are cached per (spec, shape, dispatch mode) in a
+:class:`~repro.kernels.planner.PlanCache` with hit/miss counters, emit
+``attn.plan`` telemetry events with the full modeled decision record,
+and print themselves via :meth:`AttnPlan.explain` (what
+``repro-dryrun --explain`` shows next to the GEMM plans).
 
 The pre-redesign entrypoints (``repro.kernels.ops.attention`` /
 ``decode_attention`` / ``decode_attention_paged``) live on as deprecated
@@ -49,11 +47,11 @@ from repro.core.memory_model import PIPELINE_STAGES, fits_vmem_bytes, \
 from repro.core.tiling import cdiv, dtype_bytes, round_up
 from repro.dist import sharding as shd
 from repro.kernels import ref as _ref
-from repro.kernels.api import TunedInfo, _dtname, _float0, _mode
 from repro.kernels.blocked_attention import attention_blocked
 from repro.kernels.flash_attention import flash_attention, \
     flash_attention_bwd, kv_block_range
 from repro.kernels.flash_decode import flash_decode, flash_decode_paged
+from repro.kernels.planner import PlanCache, dispatch_mode, dtname, float0
 
 #: above this many query/kv positions the unblocked reference would
 #: materialize (b, h, sq, skv) scores; the planner switches the XLA
@@ -62,10 +60,9 @@ BLOCKED_ATTN_THRESHOLD = 1024
 
 _MODES = ("prefill", "decode", "decode_paged")
 
-#: kernel families whose block sizes are free (and therefore tunable);
-#: paged decode's kv block IS the page size, and the XLA reference
-#: paths have no blocks at all
-TUNABLE_KERNELS = ("flash_attention", "attention_blocked", "flash_decode")
+#: kernel families whose block sizes are free; paged decode's kv block
+#: IS the page size, and the XLA reference paths have no blocks at all
+_FREE_BLOCK_KERNELS = ("flash_attention", "attention_blocked", "flash_decode")
 
 _FLOAT_DTYPES = ("float32", "bfloat16", "float16")
 
@@ -90,15 +87,11 @@ class AttnSpec:
     * ``group`` — the GQA ratio ``hq // hkv`` (1 = MHA; ``hkv == 1``
       at plan time makes it MQA).
     * ``q_dtype`` / ``kv_dtype`` — per-operand storage dtypes; both
-      must be floating today.  ``kv_quant`` reserves the int8-KV hook
-      (ROADMAP item) and raises until the quantized cache lands, so the
-      flag can never silently mean "ignored".
+      must be floating.
     * ``bq`` / ``bkv`` — explicit block override, honored verbatim like
       ``GemmSpec(tile=)`` (an infeasible override raises instead of
       silently re-routing).  Rejected for ``decode_paged``: its kv
       block is the page size.
-    * ``tune`` — per-spec autotune override (None = process/env
-      switch, the same three-level rule as ``GemmSpec.tune``).
     """
 
     mode: str = "prefill"
@@ -107,10 +100,8 @@ class AttnSpec:
     group: int = 1
     q_dtype: str = "bfloat16"
     kv_dtype: str = "bfloat16"
-    kv_quant: bool = False
     bq: Optional[int] = None
     bkv: Optional[int] = None
-    tune: Optional[bool] = None
 
     def __post_init__(self):
         if self.mode not in _MODES:
@@ -130,13 +121,9 @@ class AttnSpec:
                              "construct; window > 0 requires causal=True")
         for name, dt in (("q_dtype", self.q_dtype),
                          ("kv_dtype", self.kv_dtype)):
-            if _dtname(dt) not in _FLOAT_DTYPES:
+            if dtname(dt) not in _FLOAT_DTYPES:
                 raise ValueError(f"{name} must be floating "
                                  f"({_FLOAT_DTYPES}), got {dt!r}")
-        if self.kv_quant:
-            raise ValueError(
-                "kv_quant is the forward-compat hook for the int8 KV "
-                "cache (ROADMAP item) — not implemented yet")
         if self.mode == "decode_paged" and (self.bq or self.bkv):
             raise ValueError("decode_paged has no free blocks: the kv "
                              "block is the page size")
@@ -149,16 +136,14 @@ class AttnSpec:
 
     @property
     def key(self) -> str:
-        """Canonical string id — starts with ``attn|`` so tuning-cache
-        entries land in their own namespace next to the GEMM keys."""
+        """Canonical string id — starts with ``attn|`` so telemetry
+        joins never confuse it with a GEMM spec key."""
         parts = [self.mode, "causal" if self.causal else "full"]
         if self.window:
             parts.append(f"w{self.window}")
         if self.group != 1:
             parts.append(f"g{self.group}")
-        parts.append(f"{_dtname(self.q_dtype)}x{_dtname(self.kv_dtype)}")
-        if self.kv_quant:
-            parts.append("kvq")
+        parts.append(f"{dtname(self.q_dtype)}x{dtname(self.kv_dtype)}")
         s = ":".join(parts)
         if self.bq is not None or self.bkv is not None:
             s += f"!{self.bq or 0}x{self.bkv or 0}"
@@ -176,8 +161,8 @@ class AttnSpec:
             raise ValueError(f"hq ({hq}) must be a multiple of "
                              f"hkv ({hkv})")
         return cls(mode=mode, causal=causal, window=window,
-                   group=hq // hkv, q_dtype=_dtname(q.dtype),
-                   kv_dtype=_dtname(k.dtype), **kw)
+                   group=hq // hkv, q_dtype=dtname(q.dtype),
+                   kv_dtype=dtname(k.dtype), **kw)
 
 
 # ---------------------------------------------------------------------------
@@ -295,8 +280,8 @@ def attn_traffic(p: AttnProblem, kernel: str,
                  chip: TPUChip = TPU_V5E) -> bandwidth.TrafficEstimate:
     """Roofline estimate for one (kernel family, blocks) choice —
     same :class:`~repro.core.bandwidth.TrafficEstimate` contract (and
-    the same calibration-aware :func:`~repro.core.bandwidth.
-    effective_rates`) as the GEMM estimator."""
+    the same :func:`~repro.core.bandwidth.effective_rates`) as the GEMM
+    estimator."""
     hbm = float(p.q_bytes + p.o_bytes)
     if kernel in ("flash_attention", "attention_blocked"):
         hbm += p.kv_bytes(bq or p.sq)
@@ -508,8 +493,7 @@ def _choose_kernel(spec: AttnSpec, p: AttnProblem,
 def _block_candidates(kernel: str, p: AttnProblem
                       ) -> Tuple[Tuple[Optional[int], Optional[int]], ...]:
     """Deduped (bq, bkv) candidates, kernel defaults first — modeled
-    ties (decode traffic is bkv-invariant) resolve to the default, and
-    the measured tuner is the authority beyond that."""
+    ties (decode traffic is bkv-invariant) resolve to the default."""
     if kernel == "flash_attention":
         bq_cap = _pow2_cap(p.sq, 8)
         bkv_cap = _pow2_cap(p.skv, 128)
@@ -540,12 +524,12 @@ def _block_candidates(kernel: str, p: AttnProblem
 
 def attn_solve_topk(spec: AttnSpec, shapes: Tuple[int, ...],
                     k: int = 5) -> Tuple[AttnBlockDesign, ...]:
-    """The ranked analytic block candidates the autotuner sweeps —
-    VMEM-fitting (bq, bkv) choices for the kernel family the dispatch
-    mode resolves to, best modeled roofline time first (stable: ties
-    keep the kernel-default ordering)."""
+    """The ranked analytic block candidates — VMEM-fitting (bq, bkv)
+    choices for the kernel family the dispatch mode resolves to, best
+    modeled roofline time first (stable: ties keep the kernel-default
+    ordering); the plan takes the first."""
     p = _problem_for(spec, shapes)
-    kernel, _ = _choose_kernel(spec, p, _mode())
+    kernel, _ = _choose_kernel(spec, p, dispatch_mode())
     designs = []
     for bq, bkv in _block_candidates(kernel, p):
         vmem = attn_vmem_footprint(p, kernel, bq, bkv)
@@ -588,7 +572,6 @@ class AttnPlan:
     bwd: AttnBackward
     blocks: Tuple[int, int] = (0, 0)          # forward (computed, of all)
     fallback_reason: Optional[str] = None
-    tuned: Optional[TunedInfo] = None
 
     @property
     def block_share(self) -> Optional[float]:
@@ -609,10 +592,6 @@ class AttnPlan:
     @property
     def vmem_bytes(self) -> int:
         return self.vmem.total
-
-    @property
-    def source(self) -> str:
-        return "tuned" if self.tuned is not None else "analytic"
 
     @property
     def shape_key(self) -> str:
@@ -677,14 +656,6 @@ class AttnPlan:
             f"{t.t_model * 1e6:.1f} us modeled "
             f"(AI {t.arithmetic_intensity:.1f} flop/B, "
             f"{t.flops / 1e9:.2f} GFLOP)")
-        if self.tuned is not None:
-            tu = self.tuned
-            src = "cache" if tu.from_cache else f"K={tu.k_searched} sweep"
-            lines.append(
-                f"  source   : tuned ({tu.t_measured_us:.1f} us measured"
-                f" ±{tu.spread:.2f}, {src})")
-        else:
-            lines.append("  source   : analytic")
         if self.blocks[1]:
             lines.append(
                 f"  forward  : {self.blocks[0]} of {self.blocks[1]} blocks "
@@ -703,56 +674,24 @@ class AttnPlan:
         return "\n".join(lines)
 
 
-class AttnPlanCacheInfo(NamedTuple):
-    entries: int
-    hits: int
-    misses: int
-
-
-_plan_cache: dict = {}
-_executed: set = set()      # plan keys whose execute() already traced
-_plan_hits = 0
-_plan_misses = 0
-
-
-def attn_plan_cache_info() -> AttnPlanCacheInfo:
-    return AttnPlanCacheInfo(len(_plan_cache), _plan_hits, _plan_misses)
-
-
-def attn_plan_cache_clear() -> None:
-    """Drop every cached attention plan and zero the counters (tests
-    that flip ``REPRO_KERNELS`` or monkeypatch kernels must call this —
-    plans are dispatch-mode-scoped but stale monkeypatched resolutions
-    would otherwise leak)."""
-    global _plan_hits, _plan_misses
-    _plan_cache.clear()
-    _executed.clear()
-    _plan_hits = 0
-    _plan_misses = 0
-
-
-def attn_plans() -> Tuple[AttnPlan, ...]:
-    """Every attention plan resolved so far (insertion order) — what
-    ``repro-dryrun --explain`` prints next to the GEMM plans."""
-    return tuple(_plan_cache.values())
-
-
 def _plan_event(pl: AttnPlan, cache: str) -> None:
-    telemetry.counter(f"attn.plan_cache.{cache}").add(1)
-    tuned = pl.tuned
-    t_model_us = pl.traffic.t_model * 1e6
     telemetry.event(
         "attn.plan", cache=cache, spec=pl.spec.key, shape=pl.shape_key,
         dispatch=pl.dispatch, kernel=pl.kernel,
         bq=pl.bq, bkv=pl.bkv, page_size=pl.page_size or None,
         hbm_bytes=pl.hbm_bytes, vmem_bytes=pl.vmem_bytes,
-        flops=pl.flops, t_model_us=t_model_us,
-        bound=pl.traffic.bound, source=pl.source,
+        flops=pl.flops, t_model_us=pl.traffic.t_model * 1e6,
+        bound=pl.traffic.bound,
         block_share=pl.block_share, bwd_block_share=pl.bwd.block_share,
-        t_measured_us=tuned.t_measured_us if tuned else None,
-        measured_vs_model=(tuned.t_measured_us / t_model_us
-                           if tuned and t_model_us else None),
         fallback_reason=pl.fallback_reason)
+
+
+#: AttnPlans by (spec, shapes, dispatch mode): REPRO_KERNELS=pallas,
+#: interpret and ref resolve to different kernel families
+_PLANS = PlanCache("attn", _plan_event)
+attn_plan_cache_info = _PLANS.info
+attn_plan_cache_clear = _PLANS.clear
+attn_plans = _PLANS.values
 
 
 def _shape_fields(spec: AttnSpec, shapes: Tuple[int, ...]) -> dict:
@@ -794,26 +733,18 @@ def _problem_for(spec: AttnSpec, shapes: Tuple[int, ...]) -> AttnProblem:
     return AttnProblem(
         mode=spec.mode, b=f["b"], sq=f["sq"], skv=f["skv"],
         hq=f["hq"], hkv=f["hkv"], d=f["d"],
-        q_dtype=_dtname(spec.q_dtype), kv_dtype=_dtname(spec.kv_dtype),
+        q_dtype=dtname(spec.q_dtype), kv_dtype=dtname(spec.kv_dtype),
         causal=spec.causal, window=spec.window,
         page_size=f["page_size"])
-
-
-def _tune_enabled(spec: AttnSpec) -> bool:
-    if spec.tune is not None:
-        return spec.tune
-    from repro.tune import autotune as _autotune
-    return _autotune.is_enabled(None)
 
 
 def _resolve(spec: AttnSpec, shapes: Tuple[int, ...]) -> AttnPlan:
     f = _shape_fields(spec, shapes)
     p = _problem_for(spec, shapes)
-    dispatch = _mode()
+    dispatch = dispatch_mode()
     kernel, fallback = _choose_kernel(spec, p, dispatch)
-    tuned = None
     bq = bkv = None
-    if kernel in TUNABLE_KERNELS:
+    if kernel in _FREE_BLOCK_KERNELS:
         if spec.bq is not None or spec.bkv is not None:
             # explicit override: honored verbatim, but an infeasible
             # block raises instead of silently re-routing
@@ -826,30 +757,13 @@ def _resolve(spec: AttnSpec, shapes: Tuple[int, ...]) -> AttnPlan:
                     f"explicit blocks bq={bq} bkv={bkv} exceed the "
                     f"VMEM budget for {kernel} at {shapes}")
         else:
-            if _tune_enabled(spec):
-                # measured autotuning: persistent cache first, then a
-                # top-K sweep; every degradation falls through to the
-                # analytic ranking below — never an exception
-                from repro import tune as _tune
-                found = _tune.attn_lookup_or_search(spec, shapes, p)
-                if found is not None:
-                    (tq, tkv), tuned = found
-                    fit = attn_vmem_footprint(p, kernel, tq, tkv)
-                    if kernel == "attention_blocked" or _fits(fit):
-                        bq, bkv = tq, tkv
-                    else:
-                        fallback = (
-                            f"tuned blocks bq={tq} bkv={tkv} infeasible "
-                            "here; re-resolved analytically")
-                        tuned = None
-            if bq is None and bkv is None:
-                designs = attn_solve_topk(spec, shapes, k=1)
-                if designs:
-                    bq, bkv = designs[0].bq, designs[0].bkv
-                else:       # nothing fits: smallest candidate, loudly
-                    bq, bkv = _block_candidates(kernel, p)[-1]
-                    fallback = ((fallback + "; ") if fallback else "") \
-                        + "no block candidate fits VMEM"
+            designs = attn_solve_topk(spec, shapes, k=1)
+            if designs:
+                bq, bkv = designs[0].bq, designs[0].bkv
+            else:       # nothing fits: smallest candidate, loudly
+                bq, bkv = _block_candidates(kernel, p)[-1]
+                fallback = ((fallback + "; ") if fallback else "") \
+                    + "no block candidate fits VMEM"
     traffic = attn_traffic(p, kernel, bq, bkv)
     vmem = attn_vmem_footprint(p, kernel, bq, bkv)
     return AttnPlan(
@@ -857,7 +771,7 @@ def _resolve(spec: AttnSpec, shapes: Tuple[int, ...]) -> AttnPlan:
         hkv=f["hkv"], d=f["d"], page_size=f["page_size"],
         max_pages=f["max_pages"], dispatch=dispatch, kernel=kernel,
         bq=bq, bkv=bkv, problem=p, traffic=traffic, vmem=vmem,
-        fallback_reason=fallback, tuned=tuned,
+        fallback_reason=fallback,
         bwd=_plan_backward(spec, p, kernel, bq),
         blocks=(_computed_blocks(p, bq, bkv) if kernel == "flash_attention"
                 else (0, 0)))
@@ -869,20 +783,9 @@ def attn_plan(spec: AttnSpec, shapes: Tuple[int, ...]) -> AttnPlan:
     per-mode layout).  The cache key includes the dispatch mode —
     ``REPRO_KERNELS=pallas|interpret|ref`` resolve to different kernel
     families, so each gets its own entry."""
-    global _plan_hits, _plan_misses
-    key = (spec, tuple(int(x) for x in shapes), _mode())
-    hit = _plan_cache.get(key)
-    if hit is not None:
-        _plan_hits += 1
-        if telemetry.enabled():
-            _plan_event(hit, "hit")
-        return hit
-    _plan_misses += 1
-    resolved = _resolve(spec, shapes)
-    _plan_cache[key] = resolved
-    if telemetry.enabled():
-        _plan_event(resolved, "miss")
-    return resolved
+    return _PLANS.get_or_resolve(
+        (spec, tuple(int(x) for x in shapes), dispatch_mode()),
+        lambda: _resolve(spec, shapes))
 
 
 # ---------------------------------------------------------------------------
@@ -1064,13 +967,13 @@ def _attn_core_bwd(pl, scale, q_offset, res, g):
             return _decode_attention_xla(q, k, v, pos,
                                          window=spec.window)
         dq, dk, dv = jax.vjp(fwd, q, k, v)[1](g)
-        return dq, dk, dv, _float0(pos), None
+        return dq, dk, dv, float0(pos), None
 
     def fwd(q, k, v):
         return _decode_attention_paged_xla(q, k, v, page_table, pos,
                                            window=spec.window)
     dq, dk, dv = jax.vjp(fwd, q, k, v)[1](g)
-    return dq, dk, dv, _float0(pos), _float0(page_table)
+    return dq, dk, dv, float0(pos), float0(page_table)
 
 
 _attn_core.defvjp(_attn_core_fwd, _attn_core_bwd)
@@ -1079,10 +982,9 @@ _attn_core.defvjp(_attn_core_fwd, _attn_core_bwd)
 def _execute_event(pl: AttnPlan) -> None:
     if not telemetry.enabled():
         return
-    ek = (pl.spec, pl.b, pl.sq, pl.skv, pl.hq, pl.d, pl.dispatch)
-    if ek in _executed:
+    if not _PLANS.first_execute(
+            (pl.spec, pl.b, pl.sq, pl.skv, pl.hq, pl.d, pl.dispatch)):
         return
-    _executed.add(ek)
     bw = pl.bwd
     telemetry.event("attn.execute", spec=pl.spec.key, shape=pl.shape_key,
                     kernel=pl.kernel, bq=pl.bq, bkv=pl.bkv,
@@ -1139,10 +1041,10 @@ def attn_execute(pl: AttnPlan, q, k, v, *, pos=None, page_table=None,
                                       and got[0] != want_kv[0]):
             raise ValueError(
                 f"{name} shape {got} != plan's {want_kv}")
-    if _dtname(q.dtype) != _dtname(spec.q_dtype):
+    if dtname(q.dtype) != dtname(spec.q_dtype):
         raise ValueError(f"q dtype {q.dtype} != spec q_dtype "
                          f"{spec.q_dtype}")
-    if _dtname(k.dtype) != _dtname(spec.kv_dtype):
+    if dtname(k.dtype) != dtname(spec.kv_dtype):
         raise ValueError(f"k dtype {k.dtype} != spec kv_dtype "
                          f"{spec.kv_dtype}")
     if spec.mode != "prefill" and (scale is not None
@@ -1161,7 +1063,6 @@ def attn_execute(pl: AttnPlan, q, k, v, *, pos=None, page_table=None,
 def attention(q, k, v, *, causal: bool = True, window: int = 0,
               scale: Optional[float] = None,
               q_offset: Optional[int] = None,
-              tune: Optional[bool] = None,
               bq: Optional[int] = None,
               bkv: Optional[int] = None) -> jax.Array:
     """Planned multi-head attention with GQA + optional sliding window.
@@ -1169,13 +1070,12 @@ def attention(q, k, v, *, causal: bool = True, window: int = 0,
     b, sq, hq, d = q.shape
     _, skv, hkv, _ = k.shape
     spec = AttnSpec.for_operands(q, k, mode="prefill", causal=causal,
-                                 window=window, tune=tune, bq=bq, bkv=bkv)
+                                 window=window, bq=bq, bkv=bkv)
     pl = attn_plan(spec, (b, sq, skv, hq, hkv, d))
     return attn_execute(pl, q, k, v, scale=scale, q_offset=q_offset)
 
 
 def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
-                     tune: Optional[bool] = None,
                      bkv: Optional[int] = None) -> jax.Array:
     """Planned single-token attention over a dense KV cache.
     q: (b, hq, d); caches: (b, S, hkv, d); pos: (b,) int32 (a scalar
@@ -1183,7 +1083,7 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0,
     b, hq, d = q.shape
     _, skv, hkv, _ = k_cache.shape
     spec = AttnSpec.for_operands(q, k_cache, mode="decode",
-                                 window=window, tune=tune, bkv=bkv)
+                                 window=window, bkv=bkv)
     pl = attn_plan(spec, (b, skv, hq, hkv, d))
     return attn_execute(pl, q, k_cache, v_cache, pos=pos)
 

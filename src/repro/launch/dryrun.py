@@ -89,8 +89,7 @@ def _shard_bytes(struct_tree, sharding_tree) -> int:
 def run_cell(arch: str, shape_name: str, mesh_mode: str,
              debug_shape: Optional[str] = None,
              layout_name: Optional[str] = None,
-             explain: bool = False, measure: bool = False,
-             autotune: Optional[int] = None) -> dict:
+             explain: bool = False) -> dict:
     import jax
     from repro.configs.base import get_config
     from repro.core import hlo_cost, roofline
@@ -98,12 +97,6 @@ def run_cell(arch: str, shape_name: str, mesh_mode: str,
     from repro.dist import sharding as shd
     from repro.launch import specs
     from repro.launch.shapes import SHAPES, skip_reason
-
-    if autotune:
-        # measured top-K tile search for every GEMM the cell plans;
-        # winners persist to the tuning cache (REPRO_TUNE_CACHE)
-        from repro import tune
-        tune.enable(None if autotune is True else int(autotune))
 
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
@@ -161,26 +154,9 @@ def run_cell(arch: str, shape_name: str, mesh_mode: str,
     from repro import ops as rops
     rec["gemm_plan_cache"] = rops.plan_cache_info()._asdict()
     rec["attn_plan_cache"] = rops.attn_plan_cache_info()._asdict()
-    if autotune:
-        from repro import tune
-        rec["tuning_cache"] = tune.tuning_cache_info()._asdict()
-        rec["gemm_sources"] = {
-            s: sum(1 for p in rops.plans() if p.source == s)
-            for s in ("tuned", "analytic")}
-        rec["attn_sources"] = {
-            s: sum(1 for p in rops.attn_plans() if p.source == s)
-            for s in ("tuned", "analytic")}
     if explain:
         rec["gemm_plans"] = [p.explain() for p in rops.plans()]
         rec["attn_plans"] = [p.explain() for p in rops.attn_plans()]
-    if measure:
-        # the measured half: every GEMM the cell planned is executed
-        # standalone (jitted, synced) and joined with its modeled
-        # bytes/roofline time — the model-vs-measured table
-        from repro.telemetry import report as treport
-        rows = treport.model_vs_measured(rops.plans())
-        rec["model_vs_measured"] = rows
-        rec["model_vs_measured_summary"] = treport.summarize(rows)
     rec["ok"] = True
     return rec
 
@@ -258,21 +234,6 @@ def main() -> None:
                     help="print GemmPlan.explain() for every GEMM the "
                          "cell planned (kernel, tile, modeled HBM/VMEM "
                          "bytes, fallback reasons)")
-    ap.add_argument("--measure", action="store_true",
-                    help="execute every planned GEMM standalone and "
-                         "print the model-vs-measured table (modeled "
-                         "bytes + roofline time vs measured wall-clock "
-                         "per spec+shape)")
-    ap.add_argument("--autotune", nargs="?", const=True, default=None,
-                    metavar="K",
-                    help="measured top-K tile search for every GEMM the "
-                         "cell plans (winners persist to the tuning "
-                         "cache); optional K narrows the candidate sweep")
-    ap.add_argument("--calibrate", action="store_true",
-                    help="after the cell, regress the tuning cache's "
-                         "measured samples against modeled HBM bytes + "
-                         "flops and report effective per-mode bandwidth/"
-                         "compute constants with R2")
     ap.add_argument("--telemetry", default=None, metavar="PATH",
                     help="record plan events; writes PATH.jsonl + "
                          "PATH.trace.json")
@@ -298,8 +259,7 @@ def main() -> None:
     try:
         rec = run_cell(args.arch, args.shape, modes[0],
                        debug_shape=args.debug_mesh,
-                       layout_name=args.layout, explain=args.explain,
-                       measure=args.measure, autotune=args.autotune)
+                       layout_name=args.layout, explain=args.explain)
     except Exception:
         rec = {"arch": args.arch, "shape": args.shape, "mesh": modes[0],
                "ok": False, "error": traceback.format_exc()}
@@ -317,29 +277,12 @@ def main() -> None:
               f"(cache {rec['attn_plan_cache']}):")
         for text in rec["attn_plans"]:
             print(text)
-    if args.measure and rec.get("model_vs_measured"):
-        from repro.telemetry import report as treport
-        print("[dryrun] model-vs-measured (per planned GEMM):")
-        print(treport.render(rec["model_vs_measured"]))
-    if args.autotune and rec.get("tuning_cache"):
-        from repro import tune
-        print(f"[dryrun] tuning cache {tune.cache_path()}: "
-              f"{rec['tuning_cache']} gemm sources "
-              f"{rec.get('gemm_sources')} attn sources "
-              f"{rec.get('attn_sources')}")
-    if args.calibrate:
-        from repro import tune
-        fits = tune.calibrate.fit()
-        print(tune.calibrate.render(fits))
-        rec["calibration"] = {m: c.as_dict() for m, c in fits.items()}
-        with open(path, "w") as f:
-            json.dump(rec, f, indent=1)
     if args.telemetry:
         paths = telemetry.export(args.telemetry)
         print(f"[dryrun] telemetry: wrote {paths[0]} and {paths[1]}")
     print(json.dumps({k: v for k, v in rec.items()
-                      if k not in ("error", "gemm_plans", "attn_plans",
-                                   "model_vs_measured")}, indent=1))
+                      if k not in ("error", "gemm_plans", "attn_plans")},
+                     indent=1))
     if not rec["ok"]:
         print(rec.get("error", ""), file=sys.stderr)
         sys.exit(1)

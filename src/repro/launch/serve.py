@@ -96,23 +96,6 @@ def _warmup(engine: DecodeEngine, cfg, prompt_lens,
     info = ops.plan_cache_info()
     print(f"[serve] gemm plan cache after warm-up: {info.entries} "
           f"plans ({info.hits} hits / {info.misses} misses)")
-    _print_tune_info()
-
-
-def _print_tune_info() -> None:
-    """Tuning-cache state after warm-up (only when autotuning is on):
-    entries, hit/measure counters, and how many live plans took the
-    measured winner vs the analytic answer."""
-    from repro import ops
-    from repro.tune import autotune, cache_path, tuning_cache_info
-    if not autotune.is_enabled():
-        return
-    ti = tuning_cache_info()
-    plans = ops.plans()
-    tuned = sum(1 for p in plans if p.source == "tuned")
-    print(f"[serve] tuning cache {cache_path()}: {ti.entries} "
-          f"entries ({ti.hits} hits / {ti.measurements} measured); "
-          f"{tuned}/{len(plans)} plans tuned")
 
 
 def run_trace(engine: DecodeEngine, cfg, args) -> None:
@@ -177,7 +160,6 @@ def run_batch(engine: DecodeEngine, cfg, args) -> None:
     # 1-token request completes at admission without touching _step)
     engine.generate(prompts, min(2, args.steps + 1), frames=frames)
     engine.reset_metrics()
-    _print_tune_info()
     t0 = time.perf_counter()
     result = engine.generate(prompts, args.steps, frames=frames)
     dt = time.perf_counter() - t0
@@ -211,12 +193,6 @@ def main() -> None:
                          "write PATH.jsonl + PATH.trace.json (the "
                          "latter loads in chrome://tracing or "
                          "ui.perfetto.dev)")
-    ap.add_argument("--autotune", nargs="?", const=True, default=None,
-                    metavar="K",
-                    help="measured top-K tile search for every GEMM the "
-                         "warm-up plans; winners persist to the tuning "
-                         "cache so a later serve re-plans with zero "
-                         "re-measurement")
     ap.add_argument("--page-size", type=int, default=None,
                     help="block-paged KV cache with this page size "
                          "(tokens); max_len rounds up to a page "
@@ -241,10 +217,6 @@ def main() -> None:
     print(f"[serve] compile cache: {use_compile_cache()}")
     if args.telemetry:
         telemetry.enable()
-    if args.autotune:
-        from repro import tune
-        tune.enable(None if args.autotune is True
-                    else int(args.autotune))
     if args.w8a8:
         args.int8 = True
         from repro import quant

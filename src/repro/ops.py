@@ -35,7 +35,6 @@ from repro.kernels.api import (  # noqa: F401
     GemmPlan,
     GemmSpec,
     PlanCacheInfo,
-    TunedInfo,
     execute,
     gemm,
     gemm_grouped,
@@ -45,13 +44,11 @@ from repro.kernels.api import (  # noqa: F401
     plan_cache_clear,
     plan_cache_info,
     plans,
-    solve_topk,
     use_pallas,
 )
 from repro.kernels.attn_api import (  # noqa: F401
     BLOCKED_ATTN_THRESHOLD,
     AttnPlan,
-    AttnPlanCacheInfo,
     AttnProblem,
     AttnSpec,
     attention,

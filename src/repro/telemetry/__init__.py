@@ -1,7 +1,5 @@
 """``repro.telemetry`` — zero-cost-when-disabled tracing + metrics for
-the whole stack (kernel plans, serve lifecycles, train steps), plus the
-model-vs-measured report that joins planned GEMM decisions with measured
-wall-clock (:mod:`repro.telemetry.report`)."""
+the whole stack (kernel plans, serve lifecycles, train steps)."""
 
 from repro.telemetry.telemetry import (  # noqa: F401
     SCHEMA_VERSION,

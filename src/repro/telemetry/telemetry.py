@@ -297,17 +297,15 @@ class Recorder:
 
     def snapshot(self) -> dict:
         """Point-in-time metric state: counter/gauge values, event
-        volume, and the GEMM plan-cache + tuning-cache stats (every
-        snapshot carries them — the cache hit/miss trajectory is a
-        first-class telemetry signal)."""
+        volume, and the GEMM plan-cache stats (every snapshot carries
+        them — the cache hit/miss trajectory is a first-class telemetry
+        signal)."""
         from repro.kernels import api as _api  # runtime import: no cycle
-        from repro.tune import cache as _tcache
         return {
             "elapsed_s": self._now(),
             "counters": {k: c.value for k, c in self._counters.items()},
             "gauges": {k: g.value for k, g in self._gauges.items()},
             "plan_cache": _api.plan_cache_info()._asdict(),
-            "tuning_cache": _tcache.tuning_cache_info()._asdict(),
             "n_events": len(self.events),
         }
 

@@ -3,9 +3,8 @@ dispatch-mode-scoped plan cache, the (prefill / decode / paged) x
 (causal / window) x (MHA / GQA / MQA) x (pallas / interpret / ref)
 dispatch matrix with call counters, recorded fallback reasons, grads
 through the ONE generic VJP, plan-explain-vs-cost-model agreement on the
-decode-32k shape, measured block autotuning through the persistent
-``attn|`` cache namespace, and bit-identical parity of the deprecated
-legacy entrypoints against the planned path.
+decode-32k shape, and bit-identical parity of the deprecated legacy
+entrypoints against the planned path.
 """
 
 from __future__ import annotations
@@ -79,9 +78,6 @@ def test_spec_rejects_nonfloat_dtypes_and_kv_quant_hook():
         ops.AttnSpec(q_dtype="int8")
     with pytest.raises(ValueError, match="kv_dtype"):
         ops.AttnSpec(kv_dtype="int32")
-    # the forward-compat hook must never be silently ignored
-    with pytest.raises(ValueError, match="kv_quant"):
-        ops.AttnSpec(kv_quant=True)
 
 
 def test_spec_block_override_constraints():
@@ -562,53 +558,6 @@ def test_solve_topk_is_vmem_feasible_and_ranked():
     assert ts == sorted(ts)
     for d in designs:
         assert fits_vmem_bytes(d.vmem.total, attn_api.TPU_V5E)
-
-
-# ---------------------------------------------------------------------------
-# Autotune: measured block winners through the persistent attn| namespace
-# ---------------------------------------------------------------------------
-
-def test_attn_autotune_roundtrip_persistent_cache(tmp_path, monkeypatch):
-    from repro import tune
-    monkeypatch.setenv("REPRO_KERNELS", "ref")
-    monkeypatch.setenv("REPRO_TUNE_CACHE",
-                       str(tmp_path / "tune_cache.json"))
-    tune.tuning_cache_reset()
-    q, k, v = _qkv(sq=128, skv=2048)        # blocked path: tunable
-    ops.attention(q, k, v, tune=True)
-    (pl,) = ops.attn_plans()
-    assert pl.source == "tuned" and not pl.tuned.from_cache
-    assert pl.tuned.k_searched >= 1
-    info = tune.tuning_cache_info()
-    assert info.measurements == 1
-    (key,) = tune.tuning_cache().entries().keys()
-    assert key.startswith("attn|") and key.endswith("|ref")
-
-    # second process over the same file: zero re-measurement
-    tune.tuning_cache_reset()
-    ops.attn_plan_cache_clear()
-    ops.attention(q, k, v, tune=True)
-    (pl2,) = ops.attn_plans()
-    assert pl2.source == "tuned" and pl2.tuned.from_cache
-    assert tune.tuning_cache_info().measurements == 0
-    assert (pl2.bq, pl2.bkv) == (pl.bq, pl.bkv)
-    assert f"{pl2.tuned.t_measured_us:.1f} us measured" in pl2.explain()
-    tune.tuning_cache_reset()
-
-
-def test_attn_autotune_batch_proxy_scales_down_not_out():
-    from repro.tune import autotune
-    p = attn_api.AttnProblem(mode="prefill", b=256, sq=4096, skv=4096,
-                             hq=15, hkv=5, d=64)
-    spec = ops.AttnSpec(group=3)
-    shapes = (256, 4096, 4096, 15, 5, 64)
-    got = autotune._attn_proxy_shapes(spec, shapes, p, 5e10)
-    assert got is not None
-    proxy_shapes, measured_b = got
-    assert measured_b < 256 and proxy_shapes[0] == measured_b
-    assert proxy_shapes[1:] == shapes[1:]
-    # per-b flops above the budget: nothing measurable at all
-    assert autotune._attn_proxy_shapes(spec, shapes, p, 1e7) is None
 
 
 # ---------------------------------------------------------------------------

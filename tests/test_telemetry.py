@@ -17,7 +17,6 @@ import pytest
 
 from repro import ops, telemetry
 from repro.telemetry import TRACK_TID_BASE, Recorder
-from repro.telemetry import report as treport
 
 SRC = pathlib.Path(__file__).resolve().parents[1] / "src" / "repro"
 
@@ -235,24 +234,6 @@ def test_execute_event_once_per_spec_shape(rec):
     ops.gemm(x, w)
     execs = [e for e in rec.events if e["name"] == "gemm.execute"]
     assert len(execs) == 2
-
-
-def test_model_vs_measured_report(rec):
-    ops.plan_cache_clear()
-    pl = ops.plan(ops.GemmSpec(), (16, 128, 128))
-    rows = treport.model_vs_measured([pl], iters=2)
-    (r,) = rows
-    assert r["t_measured_us"] > 0 and r["t_model_us"] > 0
-    # achieved is rounded for display, so compare loosely
-    assert r["achieved"] == pytest.approx(
-        r["t_model_us"] / r["t_measured_us"], rel=5e-2)
-    assert "measured" in treport.render(rows)
-    s = treport.summarize(rows)
-    assert s["n_measured"] == 1 and s["n_skipped"] == 0
-    # over-budget plans are skipped EXPLICITLY, never silently
-    rows = treport.model_vs_measured([pl], max_flops=1)
-    assert rows[0]["t_measured_us"] is None
-    assert "flops budget" in rows[0]["note"]
 
 
 # ------------------------------------------------- serve lifecycle
